@@ -1,9 +1,9 @@
 """Tensor-sharded decode scaling on an emulated 8-device pool.
 
-Two legs, both under ``--xla_force_host_platform_device_count=8`` (the
-module re-execs itself into a subprocess with that flag when the current
-process initialized jax with fewer devices — the flag only takes effect
-before backend init):
+Two legs, both under ``--xla_force_host_platform_device_count=8``, which
+the caller puts in ``XLA_FLAGS`` before JAX starts (run directly, the
+module sets it itself; a process that already started JAX with fewer
+devices fails instead of starting a JAX child):
 
 **TP scaling at an equal per-device KV budget.**  Each device can hold
 ``BASE_SLOTS`` slots' worth of KV, so a ``tp``-wide lease serves
@@ -44,8 +44,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import time
 from typing import Dict, List
 
@@ -54,6 +52,7 @@ import numpy as np
 from benchmarks.common import OUT_DIR, write_csv
 
 ARCH = "qwen3-0.6b"
+DEVICE_FLAG = "--xla_force_host_platform_device_count=8"
 PROMPT_LEN = 8
 CHUNK = 8
 BASE_SLOTS = 4                  # per-device slot budget; slots = tp * this
@@ -263,18 +262,13 @@ def main() -> None:
     import jax
 
     if jax.device_count() < 8:
-        # jax already initialized with too few devices in this process —
-        # the host-device-count flag must be set before backend init, so
-        # re-exec the bench as a child with the flag prepended.
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                            + env.get("XLA_FLAGS", "")).strip()
-        p = subprocess.run([sys.executable, "-m", "benchmarks.bench_sharded"],
-                           env=env)
-        if p.returncode != 0:
-            raise RuntimeError(
-                f"bench_sharded subprocess exited {p.returncode}")
-        return
+        # the host-device-count flag only takes effect before the backend
+        # starts, and a process that has touched JAX must not start a JAX
+        # child: the caller sets the flag
+        raise RuntimeError(
+            f"bench_sharded needs 8 devices, JAX has {jax.device_count()}: "
+            f"set XLA_FLAGS={DEVICE_FLAG} before JAX starts, e.g. "
+            f"XLA_FLAGS={DEVICE_FLAG} python -m benchmarks.run sharded")
 
     rows = run()
     path = write_csv("sharded", rows)
@@ -323,4 +317,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # run directly: set the flag before JAX's backend starts
+    os.environ["XLA_FLAGS"] = (
+        DEVICE_FLAG + " " + os.environ.get("XLA_FLAGS", "")).strip()
     main()

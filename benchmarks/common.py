@@ -19,8 +19,13 @@ from repro.core import (
     allocate,
     fpga_core,
 )
+from repro.launch.runtime import use_compile_cache
 
 OUT_DIR = os.environ.get("BENCH_OUT", "experiments/bench")
+
+# every bench shares one persistent compilation cache (JAX_COMPILATION_CACHE_DIR
+# when set, else the checkout's .jax_cache)
+use_compile_cache()
 
 #: Table 3 of the paper (ResNet50 fps) — the calibration/validation target.
 PAPER_TABLE3_RESNET50 = {

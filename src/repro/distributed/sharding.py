@@ -40,24 +40,6 @@ from repro.models.transformer import LayerSpec, period_structure
 # ---------------------------------------------------------------------------
 
 
-def shard_map_compat(f, mesh: Mesh, *, in_specs, out_specs, manual_axes):
-    """Partial-manual shard_map across jax versions: ``jax.shard_map``
-    (axis_names = the MANUAL axes) on new jax, else
-    ``jax.experimental.shard_map.shard_map`` (auto = the complement)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(manual_axes), check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-        auto=frozenset(mesh.axis_names) - frozenset(manual_axes),
-    )
-
-
 def mesh_axis_size(mesh: Mesh, name: str) -> int:
     return mesh.shape[name] if name in mesh.axis_names else 1
 
@@ -301,14 +283,14 @@ class ActivationPolicy:
             )
             return kc, vc, pc
 
-        return shard_map_compat(
-            upd, self.mesh,
+        return jax.shard_map(
+            upd, mesh=self.mesh,
             in_specs=(
                 P(None, "model"), P(None, "model"), P(None, "model"),
                 P(), P(), P(),
             ),
             out_specs=(P(None, "model"), P(None, "model"), P(None, "model")),
-            manual_axes={"model"},
+            axis_names={"model"}, check_vma=False,
         )(k_cache, v_cache, pos_cache, k_new, v_new, cur_pos)
 
     def embed(self, table, ids):
@@ -330,11 +312,11 @@ class ActivationPolicy:
             # shard_map (CloneAllReduce check-fails on the cloned region).
             return jax.lax.psum(out.astype(jnp.float32), "model").astype(tbl.dtype)
 
-        return shard_map_compat(
-            lookup, self.mesh,
+        return jax.shard_map(
+            lookup, mesh=self.mesh,
             in_specs=(P("model", None), P()),
             out_specs=P(),
-            manual_axes={"model"},
+            axis_names={"model"}, check_vma=False,
         )(table, ids)
 
 
@@ -432,9 +414,9 @@ def cache_specs(cfg, mesh: Mesh, *, batch: int):
 # 1D "tp" axis, slot bookkeeping / page tables / draft state stay replicated,
 # and each layer costs exactly two psums (attention output + MLP output).
 # Unlike the train-side partial-manual policy above, these helpers run the
-# model *entirely* inside shard_map (manual over every mesh axis) — the only
-# mode the jax-0.4.37 SPMD partitioner handles without the PartitionId issue
-# that gates tests/test_multidevice.py.  The trick that keeps the model code
+# model *entirely* inside shard_map (manual over every mesh axis), so the
+# collectives are exactly the policy's psums and nothing is left to the SPMD
+# partitioner's propagation.  The trick that keeps the model code
 # untouched: every program is traced with a *shard-local* cfg
 # (n_heads/n_kv_heads/d_ff divided by tp, d_head unchanged), so per-shard
 # shapes are just a smaller model, and the TPShardPolicy turns the two

@@ -45,8 +45,8 @@ def _dec_kernel(
     q = q_ref[0, 0].astype(jnp.float32)                # (group, dh)
     k = k_ref[0, 0].astype(jnp.float32)                # (bc, dh)
     v = v_ref[0, 0].astype(jnp.float32)
-    pos = pos_ref[0]                                   # (bc,)
-    cur = cur_ref[0]                                   # scalar
+    pos = pos_ref[0]                                   # (1, bc)
+    cur = cur_ref[0]                                   # (1, 1)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -55,7 +55,7 @@ def _dec_kernel(
     valid = jnp.logical_and(pos >= 0, pos <= cur)
     if window is not None:
         valid = jnp.logical_and(valid, pos > cur - window)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]                                # (group, 1)
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -78,7 +78,15 @@ def decode_attention_kernel(
     block_c: int = 1024, interpret: bool = False,
 ):
     """q: (B, Hkv, group, dh); k/v: (B, Hkv, C, dh); pos: (B, C);
-    cur_pos: (B, 1) int32 → (B, Hkv, group, dh)."""
+    cur_pos: (B,) int32 → (B, Hkv, group, dh).
+
+    ``pos`` and ``cur_pos`` ride in as (B, 1, C) and (B, 1, 1) so their
+    blocks' last two dims are (1, block_c) and (1, 1) against array dims
+    (1, C) and (1, 1) — legal for the TPU's tiling, where a (1, block_c)
+    block over (B, C) is not.  VMEM per cell: K and V blocks
+    double-buffered ``4 * block_c * dh * E`` (E = cache bytes per element)
+    plus their f32 copies ``2 * block_c * dh * 4`` — 1 MiB + 1 MiB at
+    block_c 1024, dh 128, bf16."""
     B, Hkv, group, dh = q.shape
     C = k.shape[2]
     block_c = min(block_c, C)
@@ -98,8 +106,8 @@ def decode_attention_kernel(
             pl.BlockSpec((1, 1, group, dh), lambda b, h, ci: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_c, dh), lambda b, h, ci: (b, h, ci, 0)),
             pl.BlockSpec((1, 1, block_c, dh), lambda b, h, ci: (b, h, ci, 0)),
-            pl.BlockSpec((1, block_c), lambda b, h, ci: (b, ci)),
-            pl.BlockSpec((1, 1), lambda b, h, ci: (b, 0)),
+            pl.BlockSpec((1, 1, block_c), lambda b, h, ci: (b, 0, ci)),
+            pl.BlockSpec((1, 1, 1), lambda b, h, ci: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, group, dh), lambda b, h, ci: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, dh), q.dtype),
@@ -109,4 +117,4 @@ def decode_attention_kernel(
             pltpu.VMEM((group, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, pos, cur_pos)
+    )(q, k, v, pos[:, None, :], cur_pos.reshape(B, 1, 1))

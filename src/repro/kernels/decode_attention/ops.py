@@ -27,7 +27,7 @@ def decode_attention(
     kt = jnp.swapaxes(k, 1, 2)            # (B, Hkv, C, dh)
     vt = jnp.swapaxes(v, 1, 2)
     out = decode_attention_kernel(
-        qg, kt, vt, pos, cur_pos[:, None].astype(jnp.int32),
+        qg, kt, vt, pos, cur_pos.astype(jnp.int32),
         window=window, block_c=block_c, interpret=interpret,
     )
     return out.reshape(B, H, dh)

@@ -1,15 +1,15 @@
 """Paged decode-attention Pallas TPU kernel (flash-decoding over a page pool).
 
-One new token per slot attends to K/V scattered across a shared pool of
-fixed-size pages, ``(n_pages + 1, page_size, Hkv, dh)`` with a trash page at
-index ``n_pages``.  The XLA path materializes a gathered
+New tokens attend to K/V scattered across a shared pool of fixed-size
+pages, ``(n_pages + 1, page_size, Hkv, dh)`` with a trash page at index
+``n_pages``.  The XLA path materializes a gathered
 ``(B, max_pages*page_size, Hkv, dh)`` view of the pool before attending —
 the same bytes twice (pool -> gather copy -> attention read).  This kernel
 walks the slot's **page table inside the kernel** instead:
 
 * the page table (and ``cur_pos``) ride in as *scalar-prefetch* operands
   (``pltpu.PrefetchScalarGridSpec``), so the K/V BlockSpec index maps can
-  pick the physical page ``table[b, j]`` for grid step ``(b, h, j)`` — the
+  pick the physical page ``table[b, j]`` for grid step ``(b, j)`` — the
   gather becomes the DMA schedule, not a materialized array.  Pallas's
   pipeline double-buffers these page loads across the innermost grid axis
   (page ``j+1`` streams into VMEM while page ``j`` is being reduced);
@@ -18,14 +18,29 @@ walks the slot's **page table inside the kernel** instead:
 * validity is fused into the online softmax exactly like
   ``kernels/decode_attention``: paged placement is position-indexed
   (logical page j, offset o IS absolute position ``j*page_size + o``), so a
-  key is attendable iff its page is mapped and ``pos <= cur_pos`` — no
-  per-token ``pos`` array needed.
+  key is attendable iff its page is mapped and its position is not beyond
+  the query's — no per-token ``pos`` array needed.
 
-Grid = (B, Hkv, max_pages): each cell owns one (slot, kv-head) pair; the
-logical-page axis is innermost and carries the (m, l, acc) online-softmax
-scratch across steps.  All ``group`` q-heads sharing a kv head ride in one
-cell and reuse the streamed page ``group`` times (the GQA
-arithmetic-intensity win, as in the dense decode kernel).
+Grid = (B, max_pages): each cell owns one slot; the logical-page axis is
+innermost and carries the (m, l, acc) online-softmax scratch across steps.
+One block is a whole page with **all kv heads**: the pool is viewed as
+``(n_pages + 1, page_size * Hkv, dh)`` (a free reshape), so the block's
+last two dims are ``(page_size * Hkv, dh)`` — legal for the TPU's (8, 128)
+tiling, where a one-head slice ``(…, 1, dh)`` against ``Hkv`` is not.  The
+query rows of every kv head ride in the same cell as one
+``(Hkv * R, dh)`` matrix, and the scores are one
+``(Hkv * R, page_size * Hkv)`` matmul whose cross-head entries are masked
+out: ``Hkv`` times the minimal FLOPs, which decode's arithmetic intensity
+(``Hkv * R`` FLOP per KV byte, far below the chip's ridge) leaves free.
+
+VMEM per cell, with ``R`` query rows per kv head (``group`` for decode,
+``W * group`` for a W-token verify window) and ``E`` the pool's bytes per
+element: K and V blocks double-buffered ``4 * page_size * Hkv * dh * E``,
+their f32 copies ``2 * page_size * Hkv * dh * 4``, scores and
+probabilities ``2 * Hkv * R * page_size * Hkv * 4``, and the q/out blocks
+plus scratch ``~6 * Hkv * R * dh * 4``.  At qwen3-0.6b widths (Hkv 8,
+dh 128, group 2) and page_size 128: ~1 MiB (bf16) + 1 MiB + 0.5 MiB (W 4)
++ 0.2 MiB, inside the default scoped VMEM limit.
 """
 
 from __future__ import annotations
@@ -40,14 +55,21 @@ from jax.experimental.pallas import tpu as pltpu
 from ..common import NEG_INF
 
 
-def _paged_dec_kernel(
+def _paged_kernel(
     gather_ref, cur_ref,                      # scalar prefetch (SMEM)
     q_ref, k_ref, v_ref, o_ref,               # blocks (VMEM)
     m_ref, l_ref, acc_ref,                     # scratch (VMEM)
-    *, page_size: int, n_pages: int, max_pages: int,
+    *, page_size: int, n_pages: int, max_pages: int, n_kv: int,
+    rows: int, group: int,
 ):
+    """Query row ``h * rows + w * group + g`` is q-head ``g`` of kv head
+    ``h`` at window position ``w``: absolute position ``cur_pos[b] + w``,
+    attending keys at positions ``<= cur_pos[b] + w`` — which includes a
+    verify window's own K/V written by the caller before the kernel runs
+    (within-window causality falls out of the same position check).  Key
+    column ``o * n_kv + h`` is offset ``o`` of the page, kv head ``h``."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -55,24 +77,25 @@ def _paged_dec_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                # (group, dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (ps, dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0].astype(jnp.float32)                   # (n_kv*rows, dh)
+    k = k_ref[0].astype(jnp.float32)                   # (ps*n_kv, dh)
+    v = v_ref[0].astype(jnp.float32)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) / jnp.sqrt(jnp.float32(q.shape[-1]))             # (group, ps)
+    ) / jnp.sqrt(jnp.float32(q.shape[-1]))             # (n_kv*rows, ps*n_kv)
 
-    # validity fused into the running max/denominator: page mapped
-    # (gather == n_pages means the trash redirect) AND absolute position
-    # (== flat index, by paged placement) not beyond the current token
-    pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (q.shape[0], page_size), 1)
+    shape = s.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    same_head = row // rows == col % n_kv
+    pos = j * page_size + col // n_kv
+    qpos = cur_ref[b] + (row % rows) // group
     mapped = gather_ref[b, j] < n_pages
-    valid = jnp.logical_and(mapped, pos <= cur_ref[b])
+    valid = same_head & mapped & (pos <= qpos)
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...]                                # (group, 1)
+    m_prev = m_ref[...]                                # (n_kv*rows, 1)
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     scale = jnp.exp(m_prev - m_new)
@@ -85,152 +108,53 @@ def _paged_dec_kernel(
     @pl.when(j == max_pages - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def paged_decode_attention_kernel(
-    q, k_pool, v_pool, gather, cur_pos, *, interpret: bool = False,
-):
-    """q: (B, Hkv, group, dh); k_pool/v_pool: (n_pages + 1, ps, Hkv, dh);
-    gather: (B, max_pages) int32 physical page per logical page, already
-    sanitized (unmapped -> n_pages, the trash page); cur_pos: (B,) int32.
-    Returns (B, Hkv, group, dh)."""
-    B, Hkv, group, dh = q.shape
-    n_pages = k_pool.shape[0] - 1
-    page_size = k_pool.shape[1]
-    max_pages = gather.shape[1]
-
-    grid = (B, Hkv, max_pages)
-    kern = functools.partial(
-        _paged_dec_kernel, page_size=page_size, n_pages=n_pages,
-        max_pages=max_pages,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, group, dh),
-                         lambda b, h, j, g_ref, c_ref: (b, h, 0, 0)),
-            # the page walk: physical page id from the prefetched table
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, h, j, g_ref, c_ref: (g_ref[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, h, j, g_ref, c_ref: (g_ref[b, j], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, dh),
-                               lambda b, h, j, g_ref, c_ref: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),       # m
-            pltpu.VMEM((group, 1), jnp.float32),       # l
-            pltpu.VMEM((group, dh), jnp.float32),      # acc
-        ],
-    )
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, dh), q.dtype),
-        interpret=interpret,
-    )(gather, cur_pos, q, k_pool, v_pool)
-
-
-def _paged_verify_kernel(
-    gather_ref, cur_ref,                      # scalar prefetch (SMEM)
-    q_ref, k_ref, v_ref, o_ref,               # blocks (VMEM)
-    m_ref, l_ref, acc_ref,                     # scratch (VMEM)
-    *, page_size: int, n_pages: int, max_pages: int, group: int,
-):
-    """Multi-query (draft-verify) twin of :func:`_paged_dec_kernel`.
-
-    The q block carries all ``W * group`` query rows of one (slot, kv-head)
-    cell — window position ``w = row // group``, q-head ``row % group`` —
-    so one streamed page is reused ``W * group`` times.  The only change
-    from the single-query kernel is that validity is **per query row**:
-    query ``w`` sits at absolute position ``cur_pos[b] + w`` and may attend
-    keys at positions ``<= cur_pos[b] + w`` — which includes the window's
-    own K/V written by the caller before the kernel runs (within-window
-    causality falls out of the same position check, no extra mask).
-    """
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                # (W*group, dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (ps, dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) / jnp.sqrt(jnp.float32(q.shape[-1]))             # (W*group, ps)
-
-    rows = q.shape[0]
-    pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, page_size), 1)
-    qpos = cur_ref[b] + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, page_size), 0) // group
-    mapped = gather_ref[b, j] < n_pages
-    valid = jnp.logical_and(mapped, pos <= qpos)
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_ref[...]                                # (W*group, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    scale = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * scale + p.sum(axis=-1, keepdims=True)
-    m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * scale + jax.lax.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32
-    )
-
-    @pl.when(j == max_pages - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def paged_verify_attention_kernel(
+def paged_attention_kernel(
     q, k_pool, v_pool, gather, cur_pos, *, group: int,
     interpret: bool = False,
 ):
-    """q: (B, Hkv, W*group, dh) — window-major query rows per kv head;
-    k_pool/v_pool, gather, cur_pos as in
-    :func:`paged_decode_attention_kernel`.  Returns (B, Hkv, W*group, dh)."""
-    B, Hkv, wg, dh = q.shape
+    """q: (B, Hkv, R, dh) — R = W*group window-major query rows per kv
+    head (R = group for single-token decode); k_pool/v_pool:
+    (n_pages + 1, ps, Hkv, dh); gather: (B, max_pages) int32 physical page
+    per logical page, already sanitized (unmapped -> n_pages, the trash
+    page); cur_pos: (B,) int32 position of the first query row.
+    Returns (B, Hkv, R, dh)."""
+    B, Hkv, rows, dh = q.shape
     n_pages = k_pool.shape[0] - 1
     page_size = k_pool.shape[1]
     max_pages = gather.shape[1]
+    nq = Hkv * rows
 
-    grid = (B, Hkv, max_pages)
     kern = functools.partial(
-        _paged_verify_kernel, page_size=page_size, n_pages=n_pages,
-        max_pages=max_pages, group=group,
+        _paged_kernel, page_size=page_size, n_pages=n_pages,
+        max_pages=max_pages, n_kv=Hkv, rows=rows, group=group,
     )
+    page = (1, page_size * Hkv, dh)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, wg, dh),
-                         lambda b, h, j, g_ref, c_ref: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, h, j, g_ref, c_ref: (g_ref[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, h, j, g_ref, c_ref: (g_ref[b, j], 0, h, 0)),
+            pl.BlockSpec((1, nq, dh), lambda b, j, g_ref, c_ref: (b, 0, 0)),
+            # the page walk: physical page id from the prefetched table
+            pl.BlockSpec(page, lambda b, j, g_ref, c_ref: (g_ref[b, j], 0, 0)),
+            pl.BlockSpec(page, lambda b, j, g_ref, c_ref: (g_ref[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, wg, dh),
-                               lambda b, h, j, g_ref, c_ref: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, nq, dh),
+                               lambda b, j, g_ref, c_ref: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((wg, 1), jnp.float32),          # m
-            pltpu.VMEM((wg, 1), jnp.float32),          # l
-            pltpu.VMEM((wg, dh), jnp.float32),         # acc
+            pltpu.VMEM((nq, 1), jnp.float32),          # m
+            pltpu.VMEM((nq, 1), jnp.float32),          # l
+            pltpu.VMEM((nq, dh), jnp.float32),         # acc
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, wg, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, nq, dh), q.dtype),
         interpret=interpret,
-    )(gather, cur_pos, q, k_pool, v_pool)
+    )(gather, cur_pos, q.reshape(B, nq, dh),
+      k_pool.reshape(n_pages + 1, page_size * Hkv, dh),
+      v_pool.reshape(n_pages + 1, page_size * Hkv, dh))
+    return out.reshape(B, Hkv, rows, dh)

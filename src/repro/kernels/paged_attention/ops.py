@@ -4,7 +4,8 @@
 ``models.attention.paged_decode_attention(impl="pallas")`` calls: the raw
 page table (-1 = unmapped) is sanitized to trash-page redirects on the way
 in — the only per-call host-side work; the (B, max_pages*page_size) gather
-of the XLA path is never materialized.
+of the XLA path is never materialized.  Single-token decode is the
+one-token window of the verify leg: both run the same kernel.
 """
 
 from __future__ import annotations
@@ -16,10 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ..common import default_interpret
-from .kernel import (
-    paged_decode_attention_kernel,
-    paged_verify_attention_kernel,
-)
+from .kernel import paged_attention_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -30,17 +28,9 @@ def paged_decode_attention(
     """q: (B, H, dh); k_pool/v_pool: (n_pages + 1, page_size, Hkv, dh) with
     the trash page at index ``n_pages``; page_table: (B, max_pages) int32,
     -1 = unmapped; cur_pos: (B,) int32.  Returns (B, H, dh)."""
-    interpret = default_interpret() if interpret is None else interpret
-    B, H, dh = q.shape
-    Hkv = k_pool.shape[2]
-    group = H // Hkv
-    n_pages = k_pool.shape[0] - 1
-    gather = jnp.where(page_table >= 0, page_table, n_pages).astype(jnp.int32)
-    out = paged_decode_attention_kernel(
-        q.reshape(B, Hkv, group, dh), k_pool, v_pool, gather,
-        cur_pos.astype(jnp.int32), interpret=interpret,
-    )
-    return out.reshape(B, H, dh)
+    return paged_verify_attention(
+        q[:, None], k_pool, v_pool, page_table, cur_pos,
+        interpret=interpret)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -62,7 +52,7 @@ def paged_verify_attention(
     # window-major rows per kv head: row = w * group + q-head-in-group, so
     # the kernel recovers the query position as cur_pos + row // group
     qr = q.reshape(B, W, Hkv, group, dh).transpose(0, 2, 1, 3, 4)
-    out = paged_verify_attention_kernel(
+    out = paged_attention_kernel(
         qr.reshape(B, Hkv, W * group, dh), k_pool, v_pool, gather,
         cur_pos.astype(jnp.int32), group=group, interpret=interpret,
     )

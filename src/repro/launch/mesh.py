@@ -8,18 +8,14 @@ jax import, and smoke tests must keep seeing 1 device).
 from __future__ import annotations
 
 
-def make_mesh_compat(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the jax version supports
-    them (``axis_types`` and ``jax.sharding.AxisType`` only exist on newer
-    jax; older versions default to Auto/GSPMD propagation anyway)."""
+def make_auto_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types (GSPMD sharding propagation)."""
     import jax
 
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False, dp_tp=None):
@@ -44,7 +40,7 @@ def make_production_mesh(*, multi_pod: bool = False, dp_tp=None):
             "(dryrun.py must set --xla_force_host_platform_device_count=512 "
             "before importing jax)"
         )
-    return make_mesh_compat(shape, axes, devices=devices[:n])
+    return make_auto_mesh(shape, axes, devices=devices[:n])
 
 
 def make_host_mesh(shape=None, axes=("data", "model")):
@@ -56,4 +52,4 @@ def make_host_mesh(shape=None, axes=("data", "model")):
     if shape is None:
         shape = (1, len(devices))
     n = int(np.prod(shape))
-    return make_mesh_compat(shape, axes, devices=devices[:n])
+    return make_auto_mesh(shape, axes, devices=devices[:n])

@@ -36,6 +36,11 @@ def main(argv=None) -> int:
 
     import jax
 
+    from repro.launch.runtime import require_backend, use_compile_cache
+
+    platform = require_backend()
+    use_compile_cache()
+
     from repro.configs import get_config, get_reduced
     from repro.models import init_params
     from repro.serving import ServingConfig
@@ -48,7 +53,8 @@ def main(argv=None) -> int:
                                   devices_per_core=1)
     rng = np.random.default_rng(args.seed)
 
-    print(f"[serve] arch={cfg.name} tenants={args.tenants} "
+    print(f"[serve] arch={cfg.name} platform={platform} "
+          f"device={jax.devices()[0].device_kind} tenants={args.tenants} "
           f"pool={pool.n_cores} cores")
     total_toks = 0
     t0 = time.time()

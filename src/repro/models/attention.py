@@ -798,7 +798,7 @@ def paged_verify_attention(params, x, cache: PagedKVView, cur_pos,
     spanned page before the verify, all-or-nothing per slot).
 
     ``impl="pallas"`` walks the page table inside the multi-query kernel
-    (``repro.kernels.paged_attention.paged_verify_attention_kernel``);
+    (``repro.kernels.paged_attention.paged_attention_kernel``);
     ``impl="xla"`` is the gather oracle.
     """
     if policy is not None and getattr(policy, "kv_len_sharded", False):

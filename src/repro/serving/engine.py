@@ -325,12 +325,12 @@ def _tp_program(kind: str, cfg, scfg, shapes: Tuple, policy, mesh,
     keys — is replicated (identical on every shard: replicated inputs plus
     the policy's per-layer psums keep all non-head-sharded values
     bit-identical, which is what makes the replicated out_specs sound under
-    check_rep=False).  One jit, same donation pattern as the single-device
+    check_vma=False).  One jit, same donation pattern as the single-device
     twin, so the ≤1 dispatch / ≤1 sync per chunk contract is unchanged.
     """
     from jax.sharding import PartitionSpec
     from repro.distributed.sharding import (
-        shard_map_compat, tp_cache_specs, tp_local_cfg, tp_param_specs)
+        tp_cache_specs, tp_local_cfg, tp_param_specs)
 
     lcfg = tp_local_cfg(cfg, int(mesh.shape["tp"]))
 
@@ -341,10 +341,10 @@ def _tp_program(kind: str, cfg, scfg, shapes: Tuple, policy, mesh,
         in_specs[cache_in] = cspec
         out_specs = [PartitionSpec()] * n_out
         out_specs[cache_out] = cspec
-        fn = shard_map_compat(
-            build_local(lcfg), mesh,
+        fn = jax.shard_map(
+            build_local(lcfg), mesh=mesh,
             in_specs=tuple(in_specs), out_specs=tuple(out_specs),
-            manual_axes={"tp"},
+            axis_names={"tp"}, check_vma=False,
         )
         return jax.jit(fn, donate_argnums=donate)
 

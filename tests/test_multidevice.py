@@ -6,7 +6,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
 
 SCRIPT = textwrap.dedent("""
@@ -19,10 +18,10 @@ SCRIPT = textwrap.dedent("""
     from repro.models import init_params
     from repro.serving.engine import ServeConfig, make_prefill_step, make_serve_step
 
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_auto_mesh
 
     cfg = get_reduced("qwen3-0.6b")              # kv heads = 2 < model axis 4
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_auto_mesh((2, 4), ("data", "model"))
     B, S = 4, 32
     policy = make_policy(cfg, mesh, batch=B)
     assert policy.kv_len_sharded, "cache length must be model-sharded here"
@@ -62,43 +61,14 @@ SCRIPT = textwrap.dedent("""
 """)
 
 
-def test_shard_map_gate_matches_ci_expectation():
-    """A version-gated test that silently skips forever is a dead test.
-
-    Each CI matrix leg sets ``EXPECT_SHARD_MAP`` (0 on the pinned-old-jax
-    leg, 1 on the latest leg); this asserts the installed jax agrees, so
-    the gated multidevice test below is *guaranteed* to run somewhere — if
-    pip ever resolves an old jax on the latest leg (or the gate's condition
-    rots), the suite fails loudly instead of skip-passing.  Unset locally:
-    this check then skips, and the gate below speaks for itself."""
-    expect = os.environ.get("EXPECT_SHARD_MAP")
-    if expect is None:
-        pytest.skip("EXPECT_SHARD_MAP unset (local run); the CI matrix "
-                    "legs own this assertion")
-    have = hasattr(jax, "shard_map")
-    assert have == bool(int(expect)), (
-        f"CI leg expected shard_map={expect} but jax {jax.__version__} "
-        f"has shard_map={have} — the version gate on "
-        f"test_sharded_kv_decode_matches_reference is now "
-        f"{'never' if not have else 'always'} exercised on this leg"
-    )
-
-
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map (length-sharded KV slot write) emits a "
-           "PartitionId op that the SPMD partitioner of jax<0.6 cannot "
-           "handle; needs jax >= 0.6.0 (where shard_map graduated from "
-           "jax.experimental to the top-level jax.shard_map API) — this "
-           f"container has jax {jax.__version__}",
-)
 def test_sharded_kv_decode_matches_reference():
     """The partial-manual shard_map slot update (length-sharded KV cache)
     produces the same tokens/logits as the single-device reference over two
     decode steps, on a forced 2×4 host mesh."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"      # 8 emulated host devices, never a chip
     env.pop("XLA_FLAGS", None)
     p = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                        text=True, env=env, cwd=os.path.join(os.path.dirname(__file__), ".."),

@@ -22,6 +22,7 @@ import pytest
 def _run_subprocess(script: str) -> str:
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"      # 8 emulated host devices, never a chip
     env.pop("XLA_FLAGS", None)
     p = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
