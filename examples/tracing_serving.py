@@ -2,10 +2,10 @@
 
     PYTHONPATH=src python examples/tracing_serving.py
 
-Everything lands in ONE :class:`repro.obs.Telemetry` bundle — a shared
-``MetricsRegistry`` plus a shared ``Tracer`` — and exports as a single
-Chrome-trace JSON (open it at https://ui.perfetto.dev) with one track per
-tenant plus a hypervisor track:
+Parts 1 and 2 land in ONE :class:`repro.obs.Telemetry` bundle — a shared
+``MetricsRegistry`` plus a shared in-memory ``Tracer`` — and export as a
+single Chrome-trace JSON (open it at https://ui.perfetto.dev) with one
+track per tenant plus a hypervisor track:
 
 1. **Pool chaos (sim time)** — a seeded :class:`FaultInjector` drops core
    deaths onto a three-tenant hypervisor run.  Every event-loop event
@@ -18,16 +18,31 @@ tenant plus a hypervisor track:
    starved ``kv_pages`` quota so denied in-scan page faults requeue
    (``oom_requeue`` instants + the ``fault_denied_slots`` device
    counter).  Both batchers label the same registry with their tenant, so
-   ``round``/``dispatch``/``host_sync`` spans interleave on separate
-   tracks and per-request latencies feed ``slo_report`` p50/p95/p99.
+   their ``round`` spans — each holding the admission phases
+   (``admit.plan``/``admit.dispatch``/``admit.sync``/``admit.finish``) and
+   the chunk phases (``chunk.dispatch``/``chunk.sync``/``chunk.finish``) —
+   interleave on separate tracks, and per-request latencies feed
+   ``slo_report`` p50/p95/p99.
 
 The committed sample trace in ``examples/traces/`` was produced by this
 script (``max_events`` bounds its size).
+
+3. **Profiler sink (device clock)** — how an operator lays the batcher's
+   phases against the device's own trace: the same batcher spans, with
+   ``Tracer(profiler=True)``, become ``jax.profiler`` annotations on the
+   profiler's host plane while a ``jax.profiler.trace`` session runs.  The
+   session's directory (``experiments/tracing_profile/``) opens in
+   TensorBoard's profile plugin or Perfetto, spans beside the device ops;
+   the script reads the spans back with ``jax.profiler.ProfileData``.
+   Simulated time cannot be placed on that clock, so the hypervisor's
+   sim-time events stay on the in-memory tracer of parts 1 and 2.
 """
 
+import glob
 import os
 import sys
 import time
+from collections import Counter
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 sys.path.insert(0, "src")
@@ -148,6 +163,36 @@ def serving(tel: Telemetry, clock) -> ServingExecutor:
     return ex
 
 
+def profiled_serving(log_dir: str) -> None:
+    """One paged batcher traced by the JAX profiler: its phase spans land
+    on the profiler's host plane, on the device trace's clock."""
+    from jax.profiler import ProfileData
+
+    cfg = get_reduced("qwen3-0.6b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    b = ContinuousBatcher(
+        params, cfg,
+        ServingConfig(slots=4, prompt_len=PROMPT_LEN,
+                      max_len=PROMPT_LEN + MAX_NEW + 4, chunk=4,
+                      paged=True, page_size=4, n_pages=64),
+        telemetry=Telemetry(tracer=Tracer(profiler=True),
+                            tenant="tenant-c"))
+    for r in requests(cfg, 8, seed=5):
+        b.submit(r)
+    with jax.profiler.trace(log_dir):
+        b.run()
+    path = sorted(glob.glob(f"{log_dir}/plugins/profile/*/*.xplane.pb"))[-1]
+    spans = Counter(
+        ev.name for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:") for line in plane.lines
+        for ev in line.events
+        if dict(ev.stats).get("track") == "tenant-c")
+    print(f"profiler sink: {b.stats.tokens} tokens; spans on the host "
+          f"plane: " + ", ".join(f"{k} x{v}" for k, v in sorted(
+              spans.items())))
+    print(f"  profile in {log_dir} (TensorBoard profile plugin / Perfetto)")
+
+
 def main() -> None:
     base = time.perf_counter()
     clock = lambda: time.perf_counter() - base  # noqa: E731 — shared origin
@@ -171,6 +216,9 @@ def main() -> None:
     print(f"wrote {trace} ({os.path.getsize(trace) // 1024} KiB, "
           f"{len(tel.tracer.events)} events, {tel.tracer.dropped} dropped) "
           f"and {metrics} — open the trace at https://ui.perfetto.dev")
+
+    log_dir = os.path.join("experiments", "tracing_profile")
+    profiled_serving(log_dir)
 
 
 if __name__ == "__main__":

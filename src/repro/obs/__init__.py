@@ -5,9 +5,10 @@ Three dependency-free pillars shared by every layer of the stack:
 * :class:`MetricsRegistry` — typed counters/gauges/log-bucketed histograms
   with per-tenant labels; ``BatcherStats`` fields and the executor's SLO
   counters are thin views over it.
-* :class:`Tracer` — structured spans + instants on an injectable clock,
-  exported as Chrome-trace/Perfetto JSON (``NULL_TRACER`` = disabled,
-  zero-cost).
+* :class:`Tracer` — structured spans + instants, kept in memory on an
+  injectable clock and exported as Chrome-trace/Perfetto JSON, or with
+  ``profiler=True`` handed to the JAX profiler on the device trace's
+  clock (``NULL_TRACER`` = disabled, zero-cost).
 * :class:`Telemetry` — the bundle a layer accepts as one ``telemetry=``
   kwarg instead of three.
 """

@@ -169,6 +169,12 @@ class Request:
     # and re-admitted: its row is left-padded differently than the original
     # prompt, which shifts page alignment for the prefix cache
     resumed: bool = False
+    # batcher-clock stamps: ``submit`` and the first admission that popped
+    # the request off the queue (a requeued request keeps its first stamp)
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    # when the request last entered the queue: submit, or a requeue
+    _t_queued: float = dataclasses.field(default=0.0, repr=False)
     # prefix-cache nodes this request currently pins (internal)
     _prefix_nodes: List[PrefixNode] = dataclasses.field(
         default_factory=list, repr=False)
@@ -225,6 +231,15 @@ _STATS_FIELDS: Tuple[str, ...] = (
     "resume_prefix_misses",
     # tensor parallelism
     "remeshes",                 # live tp-width migrations (hypervisor resizes)
+    # admission cost (host clock, in the batcher's ``clock`` timebase)
+    "admitted",                 # joins popped off the queue (a rejoin again)
+    "admit_plan_us",            # µs spent planning admissions (admit.plan)
+    "queue_wait_us",            # Σ µs each join waited since it was queued
+    # prefill work: bucket rows × tokens each row computes, duplicate-pad
+    # rows included (cached admission computes only the suffix), against
+    # the real prompt tokens neither left padding nor served from cache
+    "prefill_tokens_computed",
+    "prefill_tokens_needed",
 )
 _STATS_FIELD_SET = frozenset(_STATS_FIELDS)
 
@@ -457,7 +472,9 @@ class ContinuousBatcher:
             else None)
         self._overlap = bool(config.overlap)
         # telemetry: registry backs every BatcherStats field; the tracer
-        # (NULL_TRACER by default — zero-cost) records round/chunk spans
+        # (NULL_TRACER by default — zero-cost) records the round's phase
+        # spans (round > admit.plan/dispatch/sync/finish and
+        # chunk.dispatch/sync/finish) and the fault instants
         self.telemetry = telemetry if telemetry is not None else Telemetry()
         self._tracer = self.telemetry.tracer
         self._track = self.telemetry.track
@@ -489,6 +506,7 @@ class ContinuousBatcher:
                 "request footprint exceeds the whole page pool"
         if req.deadline is not None:
             self._has_deadlines = True
+        req.t_submit = req._t_queued = self._clock()
         self.queue.append(req)
 
     def _free_slots(self) -> List[int]:
@@ -704,7 +722,8 @@ class ContinuousBatcher:
         self.adopt_state(state)
         self._place_state()
         self.stats.remeshes += 1
-        self._tracer.instant("remesh", self._track, args={"tp": new_tp})
+        if self._tracer.enabled:
+            self._tracer.instant("remesh", self._track, args={"tp": new_tp})
 
     # -- fault guards: requeue, watchdog, page-table audit ----------------
     def inject_stall(self, slot: int, seconds: float) -> None:
@@ -748,6 +767,7 @@ class ContinuousBatcher:
         else:
             self.stats.oom_discarded_tokens += len(req.out)
             req.out.clear()
+        req._t_queued = self._clock()
         self.queue.appendleft(req)
         return kept
 
@@ -846,8 +866,9 @@ class ContinuousBatcher:
         self.pages = self.pages._replace(
             table=self.pages.table.at[rows, cols].set(-1))
         self.stats.audit_repairs += len(entries)
-        self._tracer.instant("audit_repair", self._track,
-                             args={"entries": len(entries)})
+        if self._tracer.enabled:
+            self._tracer.instant("audit_repair", self._track,
+                                 args={"entries": len(entries)})
         new_q = corrupt - self._quarantined
         self._quarantined |= corrupt
         self.stats.quarantined_pages = len(self._quarantined)
@@ -979,20 +1000,64 @@ class ContinuousBatcher:
         return nodes, inserts
 
     def _admit(self, *, defer: bool = False) -> List[Dict[str, Any]]:
-        """Admission planning + prefill dispatch.  With ``defer=False`` the
-        post-dispatch host work (reading first tokens, completing
-        done-at-admission requests, prefix inserts, draft seeding) happens
-        inline and ``[]`` is returned; with ``defer=True`` each dispatch is
-        returned as a pending record for :meth:`_finish_admit` — the overlap
-        path dispatches admission behind the in-flight decode chunk and
-        merges both at one point per round."""
-        self._shed_expired()
+        """Admission: plan the joins (span ``admit.plan``), then one
+        prefill dispatch per group (``admit.dispatch``).  With
+        ``defer=False`` the post-dispatch host work (reading first tokens,
+        completing done-at-admission requests, prefix inserts, draft
+        seeding) happens inline and ``[]`` is returned; with ``defer=True``
+        each dispatch is returned as a pending record for
+        :meth:`_finish_admit` — the overlap path dispatches admission
+        behind the in-flight decode chunk and merges both at one point per
+        round."""
+        if not self.queue:
+            return []
+        with self._tracer.span("admit.plan", self._track) as sp:
+            if self._tracer.enabled:
+                sp.set_metadata(queued=len(self.queue))
+            t0 = self._clock()
+            self._shed_expired()
+            groups = self._plan_admission()
+            self.stats.admit_plan_us += round((self._clock() - t0) * 1e6)
+            if self._tracer.enabled:
+                sp.set_metadata(joins=sum(len(g) for _, g in groups))
+        if self.paged:
+            pending = [self._dispatch_paged(group, k) for k, group in groups]
+            if pending:
+                self.stats.shared_pages = self.kv_pool.shared
+        else:
+            pending = [self._dispatch_dense(group) for _, group in groups]
+        if defer:
+            return pending
+        for rec in pending:
+            self._finish_admit(rec)
+        return []
+
+    def _pop_join(self) -> Request:
+        """Pop the queue head for a join: stamp its first admission and
+        count the join and its wait since it was (re)queued."""
+        req = self.queue.popleft()
+        now = self._clock()
+        if req.t_admit is None:
+            req.t_admit = now
+        self.stats.admitted += 1
+        self.stats.queue_wait_us += round((now - req._t_queued) * 1e6)
+        return req
+
+    def _plan_admission(self) -> List[Tuple[int, List[Dict[str, Any]]]]:
+        """Pick this round's joins and group them into dispatches:
+        ``[(cached pages k, joins)]``.  Dense: every queued request a free
+        slot can take, one group.  Paged: head-of-line by page
+        availability, with prefix-cache plans, one group per cached-prefix
+        depth (the suffix length is a static program shape, bounded by
+        prompt_len / page_size programs)."""
         free = self._free_slots()
         if not free or not self.queue:
             return []
-        if not self.paged:
-            return self._admit_dense(free, defer=defer)
         joins: List[Dict[str, Any]] = []
+        if not self.paged:
+            while free and self.queue:
+                joins.append({"slot": free.pop(0), "req": self._pop_join()})
+            return [(0, joins)]
         planned_paths: set = set()
         witness = self._queue_path_counts()
         resident = sum(r is not None for r in self.slot_req)
@@ -1028,67 +1093,68 @@ class ContinuousBatcher:
                 self.stats.prefix_hits += 1
                 self.stats.prefill_tokens_skipped += k * self.page_size
             self._admitted_pages_since_sync += pop
-            joins.append({"slot": free.pop(0), "req": self.queue.popleft(),
+            joins.append({"slot": free.pop(0), "req": self._pop_join(),
                           "k": k, "pin": k + inserts, "pop": pop,
                           "nodes": nodes})
-        if not joins:
-            return []
-        # one dispatch per cached-prefix depth: the suffix length is a
-        # static program shape (bounded by prompt_len / page_size programs)
         by_depth: Dict[int, List[Dict[str, Any]]] = {}
         for join in joins:
             by_depth.setdefault(join["k"], []).append(join)
-        pending = [self._dispatch_paged(by_depth[k], k)
-                   for k in sorted(by_depth)]
-        self.stats.shared_pages = self.kv_pool.shared
-        if defer:
-            return pending
-        for rec in pending:
-            self._finish_admit(rec)
-        return []
+        return sorted(by_depth.items())
 
-    def _admit_dense(self, free: List[int],
-                     *, defer: bool = False) -> List[Dict[str, Any]]:
-        """The original dense-ring admission path (no paging)."""
-        joins = []
-        while free and self.queue:
-            joins.append({"slot": free.pop(0), "req": self.queue.popleft()})
-        n = len(joins)
-        nb = min(1 << (n - 1).bit_length() if n > 1 else 1, self.B)
-        toks = np.zeros((nb, self.prompt_len), dtype=np.int32)
-        slots = np.zeros((nb,), dtype=np.int32)
-        budget = np.zeros((nb,), dtype=np.int32)
-        eos = np.full((nb,), -1, dtype=np.int32)
-        for j, join in enumerate(joins):
-            slot, req = join["slot"], join["req"]
-            toks[j] = self._padded_row(req)
-            slots[j] = slot
-            budget[j] = req.max_new - len(req.out)
-            if req.eos is not None:
-                eos[j] = req.eos
-        # pad a partial bucket by repeating row 0: duplicate-index scatters
-        # then write identical values, which is deterministic
-        for j in range(n, nb):
-            toks[j] = toks[0]
-            slots[j] = slots[0]
-            budget[j] = budget[0]
-            eos[j] = eos[0]
-        pos0 = np.full((nb,), self.prompt_len, dtype=np.int32)
-        nxt, self.caches, self.state = self._admit_fn(
-            self.params, {"tokens": jnp.asarray(toks)}, self.caches,
-            self.state, jnp.asarray(slots), jnp.asarray(pos0),
-            jnp.asarray(budget), jnp.asarray(eos),
-        )
+    def _count_prefill(self, group: List[Dict[str, Any]], nb: int,
+                       k: int) -> None:
+        """Prefill work of one admission dispatch: ``nb`` bucket rows of
+        the suffix after ``k`` cached pages computed, against each join's
+        real tokens in that suffix (its row minus left padding)."""
+        start = k * self.page_size if self.paged else 0
+        self.stats.prefill_tokens_computed += nb * (self.prompt_len - start)
+        for join in group:
+            req = join["req"]
+            pad = self.prompt_len - len(req.prompt) - len(req.out)
+            self.stats.prefill_tokens_needed += \
+                self.prompt_len - max(start, pad)
+
+    def _dispatch_dense(self, joins: List[Dict[str, Any]],
+                        ) -> Dict[str, Any]:
+        """One dense-ring admission dispatch (no paging); returns the
+        pending record for :meth:`_finish_admit`."""
+        with self._tracer.span("admit.dispatch", self._track) as sp:
+            n = len(joins)
+            nb = min(1 << (n - 1).bit_length() if n > 1 else 1, self.B)
+            if self._tracer.enabled:
+                sp.set_metadata(rids=[j["req"].rid for j in joins], rows=nb,
+                                k=0)
+            toks = np.zeros((nb, self.prompt_len), dtype=np.int32)
+            slots = np.zeros((nb,), dtype=np.int32)
+            budget = np.zeros((nb,), dtype=np.int32)
+            eos = np.full((nb,), -1, dtype=np.int32)
+            for j, join in enumerate(joins):
+                slot, req = join["slot"], join["req"]
+                toks[j] = self._padded_row(req)
+                slots[j] = slot
+                budget[j] = req.max_new - len(req.out)
+                if req.eos is not None:
+                    eos[j] = req.eos
+            # pad a partial bucket by repeating row 0: duplicate-index
+            # scatters then write identical values, which is deterministic
+            for j in range(n, nb):
+                toks[j] = toks[0]
+                slots[j] = slots[0]
+                budget[j] = budget[0]
+                eos[j] = eos[0]
+            pos0 = np.full((nb,), self.prompt_len, dtype=np.int32)
+            nxt, self.caches, self.state = self._admit_fn(
+                self.params, {"tokens": jnp.asarray(toks)}, self.caches,
+                self.state, jnp.asarray(slots), jnp.asarray(pos0),
+                jnp.asarray(budget), jnp.asarray(eos),
+            )
         self.stats.prefills += 1
         self.stats.dispatches += 1
         self.stats.admit_scatter_bytes += int(
             self.stats.cache_bytes * nb / max(self.B, 1)
         )
-        rec = {"kind": "dense", "joins": joins, "nxt": nxt}
-        if defer:
-            return [rec]
-        self._finish_admit(rec)
-        return []
+        self._count_prefill(joins, nb, 0)
+        return {"kind": "dense", "joins": joins, "nxt": nxt}
 
     def _dispatch_paged(self, group: List[Dict[str, Any]],
                         k: int) -> Dict[str, Any]:
@@ -1097,74 +1163,92 @@ class ContinuousBatcher:
         otherwise.  Both return the written page-table rows, from which the
         planned full-page inserts learn their physical ids.  Returns the
         pending record for :meth:`_finish_admit` (no host sync here)."""
-        n = len(group)
-        nb = min(1 << (n - 1).bit_length() if n > 1 else 1, self.B)
-        ps = self.page_size
-        S = self.prompt_len - k * ps
-        toks = np.zeros((nb, S), dtype=np.int32)
-        slots = np.zeros((nb,), dtype=np.int32)
-        budget = np.zeros((nb,), dtype=np.int32)
-        eos = np.full((nb,), -1, dtype=np.int32)
-        pin = np.zeros((nb,), dtype=np.int32)
-        pids = np.zeros((nb, max(k, 1)), dtype=np.int32)
-        rows = [self._padded_row(join["req"]) for join in group]
-        for j, join in enumerate(group):
-            req = join["req"]
-            toks[j] = rows[j][k * ps:]
-            slots[j] = join["slot"]
-            budget[j] = req.max_new - len(req.out)
-            if req.eos is not None:
-                eos[j] = req.eos
-            pin[j] = join["pin"]
+        with self._tracer.span("admit.dispatch", self._track) as sp:
+            n = len(group)
+            nb = min(1 << (n - 1).bit_length() if n > 1 else 1, self.B)
+            if self._tracer.enabled:
+                sp.set_metadata(rids=[j["req"].rid for j in group], rows=nb,
+                                k=k)
+            ps = self.page_size
+            S = self.prompt_len - k * ps
+            toks = np.zeros((nb, S), dtype=np.int32)
+            slots = np.zeros((nb,), dtype=np.int32)
+            budget = np.zeros((nb,), dtype=np.int32)
+            eos = np.full((nb,), -1, dtype=np.int32)
+            pin = np.zeros((nb,), dtype=np.int32)
+            pids = np.zeros((nb, max(k, 1)), dtype=np.int32)
+            rows = [self._padded_row(join["req"]) for join in group]
+            for j, join in enumerate(group):
+                req = join["req"]
+                toks[j] = rows[j][k * ps:]
+                slots[j] = join["slot"]
+                budget[j] = req.max_new - len(req.out)
+                if req.eos is not None:
+                    eos[j] = req.eos
+                pin[j] = join["pin"]
+                if k:
+                    pids[j] = [node.page_id for node in join["nodes"]]
+            for j in range(n, nb):      # duplicate-pad with row 0 (see dense)
+                toks[j] = toks[0]
+                slots[j] = slots[0]
+                budget[j] = budget[0]
+                eos[j] = eos[0]
+                pin[j] = pin[0]
+                pids[j] = pids[0]
+            pos0 = np.full((nb,), self.prompt_len, dtype=np.int32)
+            real = np.zeros((nb,), dtype=bool)
+            real[:n] = True
             if k:
-                pids[j] = [node.page_id for node in join["nodes"]]
-        for j in range(n, nb):        # duplicate-pad with row 0 (see above)
-            toks[j] = toks[0]
-            slots[j] = slots[0]
-            budget[j] = budget[0]
-            eos[j] = eos[0]
-            pin[j] = pin[0]
-            pids[j] = pids[0]
-        pos0 = np.full((nb,), self.prompt_len, dtype=np.int32)
-        real = np.zeros((nb,), dtype=bool)
-        real[:n] = True
-        if k:
-            fn = cached_admit_program(self.cfg, self.scfg, k,
-                                      policy=self._policy, mesh=self._mesh)
-            nxt, self.caches, self.state, self.pages, out_rows = fn(
-                self.params, {"tokens": jnp.asarray(toks)}, self.caches,
-                self.state, self.pages, jnp.asarray(slots),
-                jnp.asarray(pos0), jnp.asarray(budget), jnp.asarray(eos),
-                jnp.asarray(real), jnp.asarray(pids), jnp.asarray(pin),
-            )
-        else:
-            nxt, self.caches, self.state, self.pages, out_rows = \
-                self._admit_fn(
+                fn = cached_admit_program(self.cfg, self.scfg, k,
+                                          policy=self._policy,
+                                          mesh=self._mesh)
+                nxt, self.caches, self.state, self.pages, out_rows = fn(
                     self.params, {"tokens": jnp.asarray(toks)}, self.caches,
                     self.state, self.pages, jnp.asarray(slots),
                     jnp.asarray(pos0), jnp.asarray(budget),
-                    jnp.asarray(eos), jnp.asarray(real), jnp.asarray(pin),
+                    jnp.asarray(eos), jnp.asarray(real), jnp.asarray(pids),
+                    jnp.asarray(pin),
                 )
+            else:
+                nxt, self.caches, self.state, self.pages, out_rows = \
+                    self._admit_fn(
+                        self.params, {"tokens": jnp.asarray(toks)},
+                        self.caches, self.state, self.pages,
+                        jnp.asarray(slots), jnp.asarray(pos0),
+                        jnp.asarray(budget), jnp.asarray(eos),
+                        jnp.asarray(real), jnp.asarray(pin),
+                    )
         self.stats.prefills += 1
         self.stats.dispatches += 1
         self.stats.admit_scatter_bytes += int(
             self.stats.cache_bytes * nb * S
             / max(self.B * self.prompt_len, 1)
         )
+        self._count_prefill(group, nb, k)
         return {"kind": "paged", "joins": group, "k": k, "nxt": nxt,
                 "out_rows": out_rows, "rows": rows}
 
     def _finish_admit(self, rec: Dict[str, Any]) -> None:
         """Post-dispatch half of one admission: read the first tokens (one
-        host sync per record), append them, complete done-at-admission
-        requests, run the planned prefix inserts, and seed the drafter
-        history for the slots that stay resident."""
-        k = rec.get("k", 0)
-        if rec["kind"] == "paged":
-            nxt_np, rows_np = jax.device_get((rec["nxt"], rec["out_rows"]))
-        else:
-            nxt_np, rows_np = np.asarray(jax.device_get(rec["nxt"])), None
+        host sync per record, span ``admit.sync``), then the host work
+        (``admit.finish``)."""
+        with self._tracer.span("admit.sync", self._track):
+            if rec["kind"] == "paged":
+                nxt_np, rows_np = jax.device_get(
+                    (rec["nxt"], rec["out_rows"]))
+            else:
+                nxt_np = np.asarray(jax.device_get(rec["nxt"]))
+                rows_np = None
         self.stats.host_syncs += 1
+        with self._tracer.span("admit.finish", self._track):
+            self._account_admit(rec, nxt_np, rows_np)
+
+    def _account_admit(self, rec: Dict[str, Any], nxt_np: np.ndarray,
+                       rows_np: Optional[np.ndarray]) -> None:
+        """Append the first tokens, complete done-at-admission requests,
+        run the planned prefix inserts, and seed the drafter history for
+        the slots that stay resident."""
+        k = rec.get("k", 0)
         seeds: List[Tuple[int, Request]] = []
         for j, join in enumerate(rec["joins"]):
             slot, req = join["slot"], join["req"]
@@ -1261,14 +1345,25 @@ class ContinuousBatcher:
 
     def _dispatch_chunk(self, active: List[int]) -> Dict[str, Any]:
         """Dispatch one decode chunk (speculative: T draft-and-verify
-        windows; otherwise T decode steps) without syncing; returns the
-        pending record for :meth:`_finish_chunk`.  When admission will be
-        dispatched behind this chunk (overlap), the fetch handles that the
-        admit program would donate are snapshotted with cheap device-side
-        copies first."""
-        T = self._pick_chunk(active)
+        windows; otherwise T decode steps) without syncing, under span
+        ``chunk.dispatch``; returns the pending record for
+        :meth:`_finish_chunk`.  When admission will be dispatched behind
+        this chunk (overlap), the fetch handles that the admit program
+        would donate are snapshotted with cheap device-side copies
+        first."""
+        with self._tracer.span("chunk.dispatch", self._track) as sp:
+            T = self._pick_chunk(active)
+            if self._tracer.enabled:
+                sp.set_metadata(T=T, active=len(active))
+            t0 = self._clock()
+            fetch = self._launch_chunk(T)
+        self.stats.chunks += 1
+        self.stats.dispatches += 1
+        return {"fetch": fetch, "t0": t0, "T": T, "active": active}
+
+    def _launch_chunk(self, T: int) -> tuple:
+        """Call the T-step chunk program and return what its sync fetches."""
         self._key, sub = jax.random.split(self._key)
-        t0 = self._clock()
         ctr = None     # (4,) int32 device counters, paged modes only
         if self._spec:
             if self.paged:
@@ -1312,31 +1407,28 @@ class ContinuousBatcher:
             # a fresh chunk output, never donated, so no copy needed even
             # when overlap admission dispatches behind this chunk)
             fetch += (ctr,)
-        self.stats.chunks += 1
-        self.stats.dispatches += 1
-        if self._tracer.enabled:
-            self._tracer.complete("dispatch", self._track, t0,
-                                  self._clock() - t0,
-                                  {"T": T, "active": len(active)})
-        return {"fetch": fetch, "t0": t0, "T": T, "active": active}
+        return fetch
 
     def _finish_chunk(self, pending: Dict[str, Any],
                       *, keep_admitted_pages: int = 0) -> None:
-        """Sync one dispatched chunk and run all host bookkeeping: token
-        emission, completion, poison/OOM requeues, page accounting, audit,
-        watchdog.  ``keep_admitted_pages`` is the number of pages admission
-        dispatched *behind* this chunk has popped — the fetched ``free_top``
-        predates those pops, so they survive the counter reset."""
-        T, active = pending["T"], pending["active"]
-        t_sync0 = self._clock() if self._tracer.enabled else 0.0
-        fetched = jax.device_get(pending["fetch"])           # ONE host sync
+        """Sync one dispatched chunk (span ``chunk.sync``) and run all host
+        bookkeeping (``chunk.finish``): token emission, completion,
+        poison/OOM requeues, page accounting, audit, watchdog.
+        ``keep_admitted_pages`` is the number of pages admission
+        dispatched *behind* this chunk has popped — the fetched
+        ``free_top`` predates those pops, so they survive the counter
+        reset."""
+        with self._tracer.span("chunk.sync", self._track):
+            fetched = jax.device_get(pending["fetch"])       # ONE host sync
         elapsed = self._clock() - pending["t0"]
-        if self._tracer.enabled:
-            t_end = pending["t0"] + elapsed
-            self._tracer.complete("host_sync", self._track, t_sync0,
-                                  t_end - t_sync0)
-            self._tracer.complete("chunk", self._track, pending["t0"],
-                                  elapsed, {"T": T, "slots": len(active)})
+        with self._tracer.span("chunk.finish", self._track):
+            self._account_chunk(pending, fetched, elapsed,
+                                keep_admitted_pages)
+
+    def _account_chunk(self, pending: Dict[str, Any], fetched: tuple,
+                       elapsed: float, keep_admitted_pages: int) -> None:
+        """Host bookkeeping of one synced chunk (see :meth:`_finish_chunk`)."""
+        T, active = pending["T"], pending["active"]
         stall_slot: Optional[int] = None
         if self._stall is not None:
             stall_slot, extra = self._stall
@@ -1384,8 +1476,9 @@ class ContinuousBatcher:
             req = self.slot_req[i]
             if req is not None and bool(poison_np[i]):
                 self.stats.poisoned_slots += 1
-                self._tracer.instant("poisoned_slot", self._track,
-                                     args={"slot": i})
+                if self._tracer.enabled:
+                    self._tracer.instant("poisoned_slot", self._track,
+                                         args={"slot": i})
                 self._requeue_slot(i, req)
         if self.paged:
             active_np = fetched[3]
@@ -1413,8 +1506,9 @@ class ContinuousBatcher:
             for i in active:
                 req = self.slot_req[i]
                 if req is not None and not bool(active_np[i]):
-                    self._tracer.instant("oom_requeue", self._track,
-                                         args={"slot": i})
+                    if self._tracer.enabled:
+                        self._tracer.instant("oom_requeue", self._track,
+                                             args={"slot": i})
                     if self._requeue_slot(i, req):
                         self.stats.oom_resumed += 1
                     self.stats.oom_requeues += 1
@@ -1463,8 +1557,7 @@ class ContinuousBatcher:
         round's admission pops are carried across the counter reset."""
         if not self._overlap:
             with self._tracer.span("round", self._track):
-                with self._tracer.span("admission", self._track):
-                    self._admit()
+                self._admit()
                 active = [i for i, r in enumerate(self.slot_req)
                           if r is not None]
                 if not active:
@@ -1476,13 +1569,10 @@ class ContinuousBatcher:
                       if r is not None]
             pending = self._dispatch_chunk(active) if active else None
             pops_before = self._admitted_pages_since_sync
-            with self._tracer.span("admission", self._track):
-                admits = self._admit(defer=True)
+            admits = self._admit(defer=True)
             round_pops = self._admitted_pages_since_sync - pops_before
             if pending is not None and admits:
                 self.stats.overlap_rounds += 1
-                self._tracer.instant("overlap_merge", self._track,
-                                     args={"admits": len(admits)})
             if pending is not None:
                 self._finish_chunk(pending, keep_admitted_pages=round_pops)
             for rec in admits:

@@ -506,26 +506,24 @@ class ServingExecutor:
             entry = {"tenant": name, "n_cores": n_cores}
             cb = self._remesh_cbs.get(name)
             if cb is not None:
-                t0 = self._clock()
-                cb(self.vpool.tp_mesh_for(new_lease))
-                entry["t_remesh"] = self._clock() - t0
-                self._tracer.complete("remesh", name, t0,
-                                      entry["t_remesh"],
-                                      {"n_cores": n_cores})
+                with self._tracer.span("remesh", name,
+                                       args={"n_cores": n_cores}):
+                    t0 = self._clock()
+                    cb(self.vpool.tp_mesh_for(new_lease))
+                    entry["t_remesh"] = self._clock() - t0
             self.reconfig_log.append(entry)
             return
         state = self.live_state.get(name)
         pulled = callable(state)
         if pulled:
             state = state()                  # pull the owner's CURRENT tree
-        t0 = self._clock()
-        prog, migrated, timing = self.compiler.reconfigure(
-            name, key, n_cores,
-            live_state=state,
-            state_specs=self.state_specs.get(name),
-        )
-        self._tracer.complete("reconfigure", name, t0,
-                              self._clock() - t0, {"n_cores": n_cores})
+        with self._tracer.span("reconfigure", name,
+                               args={"n_cores": n_cores}):
+            prog, migrated, timing = self.compiler.reconfigure(
+                name, key, n_cores,
+                live_state=state,
+                state_specs=self.state_specs.get(name),
+            )
         self.programs[name] = prog
         if name in self.live_state and not pulled:
             self.live_state[name] = migrated

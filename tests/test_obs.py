@@ -7,8 +7,13 @@ the serving integration (device counters, ≤1-dispatch/≤1-sync contract
 with telemetry enabled, trace export from a real run).
 """
 
+import glob
+import itertools
 import json
 import math
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -166,6 +171,32 @@ class TestTracer:
             NULL_TRACER.instant("y", "a")
         assert NULL_TRACER.events == []
 
+    def test_span_set_metadata_adds_args(self):
+        """A site names what it found once it knows it; on the disabled
+        span the call is a no-op that never touches the clock."""
+        tr = Tracer(clock=_FakeClock())
+        with tr.span("admit.plan", "a", args={"queued": 3}) as sp:
+            sp.set_metadata(joins=2)
+        assert tr.events[0]["args"] == {"queued": 3, "joins": 2}
+        clk = _FakeClock()
+        off = Tracer(clock=clk, enabled=False)
+        with off.span("admit.plan", "a") as sp:
+            sp.set_metadata(joins=2)
+        assert off.events == [] and clk.calls == 0
+
+    def test_obs_importable_without_jax(self):
+        """The profiler sink imports JAX lazily: ``repro.obs`` and the
+        in-memory tracer work where JAX cannot be imported."""
+        import repro.obs
+
+        src = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(repro.obs.__file__))))
+        code = (f"import sys; sys.path.insert(0, {src!r})\n"
+                "sys.modules['jax'] = None\n"
+                "from repro.obs import Tracer\n"
+                "with Tracer().span('x'):\n    pass\n")
+        subprocess.run([sys.executable, "-c", code], check=True)
+
     def test_max_events_drops_counted(self):
         tr = Tracer(clock=_FakeClock(), max_events=2)
         for _ in range(5):
@@ -238,6 +269,36 @@ from repro.serving import ServingConfig                       # noqa: E402
 from repro.serving.batcher import ContinuousBatcher, Request  # noqa: E402
 
 
+#: the batcher's phase spans; each lies inside a ``round`` span
+PHASES = ("admit.plan", "admit.dispatch", "admit.sync", "admit.finish",
+          "chunk.dispatch", "chunk.sync", "chunk.finish")
+
+
+def _assert_phases_in_rounds(spans):
+    """``spans``: (name, start, end).  Every phase span lies inside a
+    ``round`` span."""
+    rounds = [(s, e) for n, s, e in spans if n == "round"]
+    for n, s, e in spans:
+        if n in PHASES:
+            assert any(rs <= s and e <= re_ for rs, re_ in rounds), (n, s)
+
+
+def _host_events(log_dir):
+    """(name, start_ns, end_ns, stats) of every event on the profiler's
+    host planes, read back from the trace the session wrote."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{log_dir}/plugins/profile/*/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.extend((ev.name, ev.start_ns,
+                            ev.start_ns + ev.duration_ns, dict(ev.stats))
+                           for ev in line.events)
+    return out
+
+
 @pytest.fixture(scope="module")
 def qwen():
     cfg = get_reduced("qwen3-0.6b")
@@ -262,7 +323,8 @@ class TestServingTelemetry:
     def test_contract_and_trace_with_telemetry_enabled(self, qwen):
         """Tracing must not add dispatches or syncs: a clean paged run keeps
         dispatches == syncs == chunks + prefills, and the exported trace
-        carries the round/dispatch/host_sync spans on the tenant track."""
+        carries the round and phase spans on the tenant track, one
+        dispatch span per dispatch and one sync span per sync."""
         cfg, params = qwen
         tel = Telemetry(tracer=Tracer(), tenant="tenantA")
         sc = ServingConfig(slots=4, prompt_len=8, max_len=64, chunk=8,
@@ -272,10 +334,16 @@ class TestServingTelemetry:
         assert all(r.done for r in reqs)
         assert st.dispatches == st.chunks + st.prefills
         assert st.host_syncs == st.chunks + st.prefills
-        names = {e["name"] for e in tel.tracer.events}
-        assert {"round", "dispatch", "host_sync", "chunk",
-                "admission"} <= names
+        names = [e["name"] for e in tel.tracer.events]
+        assert set(PHASES) | {"round"} <= set(names)
+        assert names.count("chunk.dispatch") == st.chunks
+        assert names.count("admit.dispatch") == st.prefills
+        assert names.count("chunk.sync") + names.count("admit.sync") \
+            == st.host_syncs
         assert tel.tracer.tracks() == ["tenantA"]
+        _assert_phases_in_rounds(
+            [(e["name"], e["ts"], e["ts"] + e["dur"])
+             for e in tel.tracer.events if e["ph"] == "X"])
         # stats landed in the shared registry under the tenant label
         assert tel.registry.counter("serving.chunks", "tenantA").value \
             == st.chunks
@@ -334,6 +402,147 @@ class TestServingTelemetry:
         _, traced, _ = _run(params, cfg, sc,
                             telemetry=Telemetry(tracer=Tracer()))
         assert [r.out for r in plain] == [r.out for r in traced]
+
+
+class TestProfilerSink:
+    def test_span_lands_on_host_plane_with_args(self, tmp_path):
+        """A profiler-sink span is a ``TraceAnnotation``: inside a profiler
+        session it lands on the host plane with its track and args (a list
+        as its text); nothing is kept in memory, and pre-measured stamps
+        are refused."""
+        tr = Tracer(profiler=True)
+        with jax.profiler.trace(str(tmp_path)):
+            with tr.span("admit.plan", "tenantA", args={"queued": 3}) as sp:
+                sp.set_metadata(joins=2, rids=[4, 5])
+            tr.instant("oom_requeue", "tenantA", args={"slot": 1})
+        evs = {n: (s, e, st) for n, s, e, st in _host_events(tmp_path)}
+        assert evs["admit.plan"][2] == {"track": "tenantA", "queued": 3,
+                                        "joins": 2, "rids": "[4, 5]"}
+        assert evs["oom_requeue"][2] == {"track": "tenantA", "slot": 1}
+        assert evs["admit.plan"][1] <= evs["oom_requeue"][0]
+        assert tr.events == []
+        with pytest.raises(ValueError):
+            tr.complete("recovery", "tenantA", 0.0, 1.0)
+        with pytest.raises(ValueError):
+            tr.instant("arrival", "tenantA", ts=1.0)
+
+    @pytest.mark.parametrize("overlap", [False, True])
+    def test_serving_run_phases_nested_in_rounds(self, qwen, tmp_path,
+                                                 overlap):
+        """A paged serving run under the profiler sink puts ``round`` and
+        every admission and chunk phase on the host plane, on the batcher's
+        track, one ``chunk.dispatch`` per chunk and one ``admit.dispatch``
+        per prefill, each phase inside a round."""
+        cfg, params = qwen
+        sc = ServingConfig(slots=4, prompt_len=8, max_len=64, chunk=8,
+                           attn_impl="xla", paged=True, page_size=8,
+                           n_pages=64, overlap=overlap)
+        tel = Telemetry(tracer=Tracer(profiler=True), tenant="tenantA")
+        with jax.profiler.trace(str(tmp_path)):
+            _, reqs, st = _run(params, cfg, sc, telemetry=tel)
+        assert all(r.done for r in reqs)
+        spans = [(n, s, e) for n, s, e, stats in _host_events(tmp_path)
+                 if stats.get("track") == "tenantA"]
+        names = [n for n, _, _ in spans]
+        assert set(PHASES) | {"round"} <= set(names)
+        assert names.count("chunk.dispatch") == st.chunks
+        assert names.count("admit.dispatch") == st.prefills
+        _assert_phases_in_rounds(spans)
+
+
+def _requests(cfg, lengths, *, max_new=4, seed=0, namespace=None,
+              prefix=None, rid0=0):
+    rng = np.random.default_rng(seed)
+    out = []
+    for j, n in enumerate(lengths):
+        prompt = rng.integers(1, cfg.vocab, size=n).astype(np.int32)
+        if prefix is not None:
+            prompt[:len(prefix)] = prefix
+        out.append(Request(rid=rid0 + j, prompt=prompt, max_new=max_new,
+                           namespace=namespace))
+    return out
+
+
+class TestAdmissionCounters:
+    def test_submit_before_admit_and_requeue_keeps_first_stamp(self, qwen):
+        """Every request is stamped at submit and at its first admission,
+        in that order; an OOM-requeued request rejoins (``admitted`` counts
+        it again) but keeps its first ``t_admit``."""
+        cfg, params = qwen
+        sc = ServingConfig(slots=4, prompt_len=8, max_len=64, chunk=8,
+                           attn_impl="xla", paged=True, page_size=8,
+                           n_pages=16, page_quota=5, reserve_pages=False)
+        ticks = itertools.count()
+        b = ContinuousBatcher(params, cfg, sc,
+                              clock=lambda: float(next(ticks)))
+        rng = np.random.default_rng(17)
+        reqs = [Request(rid=i,
+                        prompt=rng.integers(1, cfg.vocab,
+                                            size=1 + i % 6).astype(np.int32),
+                        max_new=10 + i % 4)
+                for i in range(8)]
+        for r in reqs:
+            b.submit(r)
+        first = {}
+        for _ in range(4000):
+            if not (b.queue or any(r is not None for r in b.slot_req)):
+                break
+            b.step()
+            for r in reqs:
+                if r.t_admit is not None:
+                    first.setdefault(r.rid, r.t_admit)
+        assert all(r.done for r in reqs)
+        assert b.stats.oom_requeues > 0, "quota never forced a requeue"
+        assert b.stats.admitted > len(reqs)
+        for r in reqs:
+            assert r.t_submit <= r.t_admit == first[r.rid]
+
+    @pytest.mark.parametrize("paged", [True, False])
+    def test_three_joins_in_a_bucket_of_four(self, qwen, paged):
+        """3 requests join one round: the bucket is 4 rows, one of them a
+        duplicate of row 0.  Computed: 4 rows x 8 tokens; needed: the
+        prompts' own 5 + 8 + 3 tokens; each waited 2.5 s on the clock."""
+        cfg, params = qwen
+        now = [0.0]
+        sc = ServingConfig(slots=4, prompt_len=8, max_len=32, chunk=4,
+                           attn_impl="xla", paged=paged, page_size=4,
+                           n_pages=64 if paged else None)
+        b = ContinuousBatcher(params, cfg, sc, clock=lambda: now[0])
+        for r in _requests(cfg, (5, 8, 3)):
+            b.submit(r)
+        now[0] = 2.5
+        b.step()
+        st = b.stats
+        assert st.prefills == 1 and st.admitted == 3
+        assert st.prefill_tokens_computed == 4 * 8
+        assert st.prefill_tokens_needed == 5 + 8 + 3
+        assert st.queue_wait_us == 3 * 2_500_000
+        assert st.admit_plan_us == 0                # the clock stood still
+
+    def test_cached_admission_counts_the_suffix(self, qwen):
+        """A cached admission computes only the suffix after the cached
+        pages: 3 hits on a 2-page (8-token) prefix of 16-token prompts
+        compute 4 rows x 8 tokens and need 3 x 8."""
+        cfg, params = qwen
+        sc = ServingConfig(slots=4, prompt_len=16, max_len=32, chunk=4,
+                           attn_impl="xla", paged=True, page_size=4,
+                           n_pages=64, prefix_cache=True)
+        b = ContinuousBatcher(params, cfg, sc)
+        doc = np.random.default_rng(5).integers(1, cfg.vocab, size=8)
+        for r in _requests(cfg, (16, 16), namespace="d", prefix=doc):
+            b.submit(r)             # the pair's recurrence inserts the doc
+        b.run()
+        assert b.stats.prefix_inserts == 2
+        before = b.stats.as_dict()
+        for r in _requests(cfg, (16, 16, 16), namespace="d", prefix=doc,
+                           seed=1, rid0=10):
+            b.submit(r)
+        b.step()
+        d = {k: v - before[k] for k, v in b.stats.as_dict().items()}
+        assert d["prefix_hits"] == 3 and d["prefills"] == 1
+        assert d["admitted"] == 3
+        assert d["prefill_tokens_computed"] == 4 * 8
+        assert d["prefill_tokens_needed"] == 3 * 8
 
 
 # ---------------------------------------------------------------------------
