@@ -2,15 +2,19 @@
 
 New tokens attend to K/V scattered across a shared pool of fixed-size
 pages, ``(n_pages + 1, page_size, Hkv, dh)`` with a trash page at index
-``n_pages``.  The XLA path materializes a gathered
-``(B, max_pages*page_size, Hkv, dh)`` view of the pool before attending —
-the same bytes twice (pool -> gather copy -> attention read).  This kernel
+``n_pages``, one such pool per layer stacked as
+``(n_layers, n_pages + 1, page_size, Hkv, dh)``.  The XLA path
+materializes a gathered ``(B, max_pages*page_size, Hkv, dh)`` view of the
+pool before attending — the same bytes twice (pool -> gather copy ->
+attention read).  This kernel
 walks the slot's **page table inside the kernel** instead:
 
-* the page table (and ``cur_pos``) ride in as *scalar-prefetch* operands
-  (``pltpu.PrefetchScalarGridSpec``), so the K/V BlockSpec index maps can
-  pick the physical page ``table[b, j]`` for grid step ``(b, j)`` — the
-  gather becomes the DMA schedule, not a materialized array.  Pallas's
+* the page table, ``cur_pos`` and the layer index ride in as
+  *scalar-prefetch* operands (``pltpu.PrefetchScalarGridSpec``), so the
+  K/V BlockSpec index maps can pick the physical page
+  ``(layer, table[b, j])`` of the stacked pool for grid step ``(b, j)`` —
+  the gather becomes the DMA schedule, not a materialized array, and the
+  layer's pool is never sliced out of the stack.  Pallas's
   pipeline double-buffers these page loads across the innermost grid axis
   (page ``j+1`` streams into VMEM while page ``j`` is being reduced);
 * unmapped logical pages are redirected to the trash page for the *load*
@@ -24,8 +28,8 @@ walks the slot's **page table inside the kernel** instead:
 Grid = (B, max_pages): each cell owns one slot; the logical-page axis is
 innermost and carries the (m, l, acc) online-softmax scratch across steps.
 One block is a whole page with **all kv heads**: the pool is viewed as
-``(n_pages + 1, page_size * Hkv, dh)`` (a free reshape), so the block's
-last two dims are ``(page_size * Hkv, dh)`` — legal for the TPU's (8, 128)
+``(n_layers, n_pages + 1, page_size * Hkv, dh)`` (a free reshape), so the
+block's last two dims are ``(page_size * Hkv, dh)`` — legal for the TPU's (8, 128)
 tiling, where a one-head slice ``(…, 1, dh)`` against ``Hkv`` is not.  The
 query rows of every kv head ride in the same cell as one
 ``(Hkv * R, dh)`` matrix, and the scores are one
@@ -56,7 +60,7 @@ from ..common import NEG_INF
 
 
 def _paged_kernel(
-    gather_ref, cur_ref,                      # scalar prefetch (SMEM)
+    gather_ref, cur_ref, layer_ref,           # scalar prefetch (SMEM)
     q_ref, k_ref, v_ref, o_ref,               # blocks (VMEM)
     m_ref, l_ref, acc_ref,                     # scratch (VMEM)
     *, page_size: int, n_pages: int, max_pages: int, n_kv: int,
@@ -78,8 +82,8 @@ def _paged_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0].astype(jnp.float32)                   # (n_kv*rows, dh)
-    k = k_ref[0].astype(jnp.float32)                   # (ps*n_kv, dh)
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                # (ps*n_kv, dh)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -112,18 +116,19 @@ def _paged_kernel(
 
 
 def paged_attention_kernel(
-    q, k_pool, v_pool, gather, cur_pos, *, group: int,
+    q, k_pool, v_pool, gather, cur_pos, layer, *, group: int,
     interpret: bool = False,
 ):
     """q: (B, Hkv, R, dh) — R = W*group window-major query rows per kv
-    head (R = group for single-token decode); k_pool/v_pool:
-    (n_pages + 1, ps, Hkv, dh); gather: (B, max_pages) int32 physical page
-    per logical page, already sanitized (unmapped -> n_pages, the trash
-    page); cur_pos: (B,) int32 position of the first query row.
+    head (R = group for single-token decode); k_pool/v_pool: the stacked
+    pools (n_layers, n_pages + 1, ps, Hkv, dh); gather: (B, max_pages)
+    int32 physical page per logical page, already sanitized (unmapped ->
+    n_pages, the trash page); cur_pos: (B,) int32 position of the first
+    query row; layer: (1,) int32, the pool of the stack to attend.
     Returns (B, Hkv, R, dh)."""
     B, Hkv, rows, dh = q.shape
-    n_pages = k_pool.shape[0] - 1
-    page_size = k_pool.shape[1]
+    n_layers, n_pages, page_size = k_pool.shape[:3]
+    n_pages -= 1                                       # the trash page
     max_pages = gather.shape[1]
     nq = Hkv * rows
 
@@ -131,30 +136,29 @@ def paged_attention_kernel(
         _paged_kernel, page_size=page_size, n_pages=n_pages,
         max_pages=max_pages, n_kv=Hkv, rows=rows, group=group,
     )
-    page = (1, page_size * Hkv, dh)
+    page = (1, 1, page_size * Hkv, dh)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, nq, dh), lambda b, j, g_ref, c_ref: (b, 0, 0)),
+            pl.BlockSpec((1, nq, dh), lambda b, j, g, c, l: (b, 0, 0)),
             # the page walk: physical page id from the prefetched table
-            pl.BlockSpec(page, lambda b, j, g_ref, c_ref: (g_ref[b, j], 0, 0)),
-            pl.BlockSpec(page, lambda b, j, g_ref, c_ref: (g_ref[b, j], 0, 0)),
+            pl.BlockSpec(page, lambda b, j, g, c, l: (l[0], g[b, j], 0, 0)),
+            pl.BlockSpec(page, lambda b, j, g, c, l: (l[0], g[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, nq, dh),
-                               lambda b, j, g_ref, c_ref: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, nq, dh), lambda b, j, g, c, l: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((nq, 1), jnp.float32),          # m
             pltpu.VMEM((nq, 1), jnp.float32),          # l
             pltpu.VMEM((nq, dh), jnp.float32),         # acc
         ],
     )
+    view = (n_layers, n_pages + 1, page_size * Hkv, dh)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nq, dh), q.dtype),
         interpret=interpret,
-    )(gather, cur_pos, q.reshape(B, nq, dh),
-      k_pool.reshape(n_pages + 1, page_size * Hkv, dh),
-      v_pool.reshape(n_pages + 1, page_size * Hkv, dh))
+    )(gather, cur_pos, layer, q.reshape(B, nq, dh),
+      k_pool.reshape(view), v_pool.reshape(view))
     return out.reshape(B, Hkv, rows, dh)

@@ -587,6 +587,12 @@ class PagedKVView(NamedTuple):
     ``page mapped and position <= cur_pos``.  (A slot only ever attends
     to positions it has itself written since acquiring the page, so
     stale contents of recycled pages can never leak across slots.)
+
+    The decode and verify steps take the stacked view of every layer's
+    pool, k, v: (n_layers, n_pages + 1, page_size, Hkv, dh), with a
+    ``layer`` index: the pool rides whole through the layer scan and is
+    written in place, never sliced per layer.  ``n_pages`` and
+    ``page_size`` read the same from one layer's pool and from the stack.
     """
 
     k: jax.Array
@@ -594,11 +600,11 @@ class PagedKVView(NamedTuple):
 
     @property
     def n_pages(self) -> int:
-        return self.k.shape[0] - 1
+        return self.k.shape[-4] - 1
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[1]
+        return self.k.shape[-3]
 
 
 def init_paged_kv_cache(cfg, n_pages: int, page_size: int, *, dtype=None) -> PagedKVView:
@@ -615,14 +621,18 @@ def init_paged_kv_cache(cfg, n_pages: int, page_size: int, *, dtype=None) -> Pag
 
 
 def paged_decode_attention(params, x, cache: PagedKVView, cur_pos, page_table,
-                           cfg, *, impl: str = "xla", policy=None):
+                           cfg, *, layer, impl: str = "xla", policy=None):
     """Single-token decode against a paged pool.
 
     x: (B, 1, D); cur_pos: (B,) absolute position of the new token;
-    page_table: (B, max_pages) int32 physical page per logical page.
+    page_table: (B, max_pages) int32 physical page per logical page;
+    ``cache`` is the stacked view of every layer's pool and ``layer``
+    (int32 scalar) the one this call writes and attends.
 
-    The new token's K/V is written at (page_table[b, cur_pos // ps],
-    cur_pos % ps); unmapped slots write to the trash page.
+    The new token's K/V is written at (layer, page_table[b, cur_pos // ps],
+    cur_pos % ps) — one scatter into the stack, which a carried, donated
+    pool takes in place; unmapped slots write to the trash page.  The
+    returned view is the whole stack.
 
     ``impl="xla"`` gathers the slot's pages into a
     (B, max_pages*ps, Hkv, dh) view before attending — the pool bytes
@@ -653,18 +663,18 @@ def paged_decode_attention(params, x, cache: PagedKVView, cur_pos, page_table,
     pid = jnp.take_along_axis(page_table, logical[:, None], axis=1)[:, 0]
     dest = jnp.where(pid >= 0, pid, P)                         # trash if unmapped
     off = cur_pos % ps
-    k = cache.k.at[dest, off].set(k_new[:, 0])
-    v = cache.v.at[dest, off].set(v_new[:, 0])
+    k = cache.k.at[layer, dest, off].set(k_new[:, 0])
+    v = cache.v.at[layer, dest, off].set(v_new[:, 0])
 
     if impl == "pallas":
         from repro.kernels.paged_attention import ops as pa_ops
 
         out = pa_ops.paged_decode_attention(
-            q[:, 0], k, v, page_table, cur_pos)[:, None]
+            q[:, 0], k, v, page_table, cur_pos, layer)[:, None]
     else:
         gather = jnp.where(page_table >= 0, page_table, P)     # (B, maxp)
-        kg = k[gather]                                         # (B, maxp, ps, Hkv, dh)
-        vg = v[gather]
+        kg = k[layer, gather]                                  # (B, maxp, ps, Hkv, dh)
+        vg = v[layer, gather]
         maxp = page_table.shape[1]
         L = maxp * ps
         kg = kg.reshape(B, L, cfg.n_kv_heads, cfg.d_head)
@@ -786,13 +796,14 @@ def _verify_attn_xla(q, k, v, pos, q_pos, cfg):
 
 
 def paged_verify_attention(params, x, cache: PagedKVView, cur_pos,
-                           page_table, cfg, *, impl: str = "xla",
+                           page_table, cfg, *, layer, impl: str = "xla",
                            policy=None):
     """Multi-query decode attention for draft verification (paged pool).
 
-    x: (B, W, D); cur_pos: (B,) first window position; page_table as in
+    x: (B, W, D); cur_pos: (B,) first window position; page_table,
+    ``cache`` (the stacked pools) and ``layer`` as in
     :func:`paged_decode_attention`.  The window's K/V is scattered at
-    ``(page_table[b, pos // ps], pos % ps)`` per token; positions whose
+    ``(layer, page_table[b, pos // ps], pos % ps)`` per token; positions whose
     logical page is unmapped or out of table range land on the trash page
     (allocation is the caller's job — the spec chunk scan faults every
     spanned page before the verify, all-or-nothing per slot).
@@ -819,18 +830,20 @@ def paged_verify_attention(params, x, cache: PagedKVView, cur_pos,
                               jnp.clip(logical, 0, maxp - 1), axis=1)
     dest = jnp.where((pid >= 0) & (logical < maxp), pid, P)        # trash
     off = pos_w % ps
-    k = cache.k.at[dest, off].set(k_new)
-    v = cache.v.at[dest, off].set(v_new)
+    k = cache.k.at[layer, dest, off].set(k_new)
+    v = cache.v.at[layer, dest, off].set(v_new)
 
     if impl == "pallas":
         from repro.kernels.paged_attention import ops as pa_ops
 
-        out = pa_ops.paged_verify_attention(q, k, v, page_table, cur_pos)
+        out = pa_ops.paged_verify_attention(q, k, v, page_table, cur_pos,
+                                            layer)
     else:
         gather = jnp.where(page_table >= 0, page_table, P)         # (B, maxp)
-        kg = k[gather].reshape(B, maxp * ps, cfg.n_kv_heads, cfg.d_head)
-        vg = v[gather].reshape(B, maxp * ps, cfg.n_kv_heads, cfg.d_head)
-        pos_l = jnp.arange(maxp * ps, dtype=jnp.int32)             # absolute
+        L = maxp * ps
+        kg = k[layer, gather].reshape(B, L, cfg.n_kv_heads, cfg.d_head)
+        vg = v[layer, gather].reshape(B, L, cfg.n_kv_heads, cfg.d_head)
+        pos_l = jnp.arange(L, dtype=jnp.int32)                     # absolute
         valid = (page_table >= 0)[:, pos_l // ps][:, None, :] & (
             pos_l[None, None, :] <= pos_w[:, :, None])             # (B, W, L)
         out = _paged_verify_attn_xla(q, kg, vg, valid, cfg)

@@ -199,15 +199,17 @@ def _apply_layer_train(
 
 def _apply_layer_decode(
     lp, spec: LayerSpec, x, cfg, *, cur_pos, kv_cache, ssm_state, cross_kv,
-    impl, policy, page_table=None,
+    impl, policy, page_table=None, layer=None,
 ):
-    """One layer, single-token decode.  Returns (x, new_kv, new_ssm)."""
+    """One layer, single-token decode.  Returns (x, new_kv, new_ssm).
+    With ``page_table``, ``kv_cache`` is the stacked paged pool and
+    ``layer`` this layer's index into it."""
     h = rmsnorm(lp["ln1"], x, eps=cfg.norm_eps)
     new_kv, new_ssm = kv_cache, ssm_state
     if spec.mixer == "attn" and page_table is not None:
         y, new_kv = attn_mod.paged_decode_attention(
             lp["attn"], h, kv_cache, cur_pos, page_table, cfg,
-            impl=impl, policy=policy,
+            layer=layer, impl=impl, policy=policy,
         )
     elif spec.mixer == "attn":
         y, new_kv = attn_mod.decode_attention(
@@ -587,6 +589,11 @@ def decode_step(
     With ``page_table`` (B, max_pages) the attention caches are treated as
     paged pools (:func:`init_paged_caches`); the table is read-only here —
     page allocation happens in the caller (chunk scan body or admission).
+    The stacked pools then ride in the layer scan's carry and each layer
+    writes its token into them by layer index, so the (donated) pool is
+    updated in place: as ``xs``/``ys`` each layer's pool would be sliced
+    out, written back and copied.  Dense rings and SSM state are per slot
+    and small, and stay in ``xs``/``ys``.
     """
     specs = period_structure(cfg)
     x = _embed(params, tokens, policy)[:, None, :]     # (B, 1, d)
@@ -594,32 +601,41 @@ def decode_step(
         x = x + _sinusoid(cur_pos[:, None], cfg.d_model).astype(x.dtype)
     x = _shard(x, policy, "hidden_decode")
 
-    have_cross = caches.cross is not None and len(caches.cross) > 0
+    cross = caches.cross or {}
 
-    def body(x, xs_in):
-        if have_cross:
-            block_params, kv_in, ssm_in, cross_in = xs_in
-        else:
-            block_params, kv_in, ssm_in = xs_in
-            cross_in = {}
+    def layers(x, block_params, kv_in, ssm_in, cross_in, layer=None):
         kv_out, ssm_out = {}, {}
         for p, spec in enumerate(specs):
             x, nkv, nssm = _apply_layer_decode(
                 block_params[p], spec, x, cfg, cur_pos=cur_pos,
                 kv_cache=kv_in.get(str(p)), ssm_state=ssm_in.get(str(p)),
                 cross_kv=cross_in.get(str(p)), impl=impl, policy=policy,
-                page_table=page_table,
+                page_table=page_table, layer=layer,
             )
             if spec.mixer == "attn":
                 kv_out[str(p)] = nkv
             else:
                 ssm_out[str(p)] = nssm
-        return x, (kv_out, ssm_out)
+        return x, kv_out, ssm_out
 
-    xs = (params["blocks"], caches.kv, caches.ssm)
-    if have_cross:
-        xs = xs + (caches.cross,)
-    x, (kv_new, ssm_new) = jax.lax.scan(body, x, xs)
+    if page_table is None:
+        def body(x, xs_in):
+            x, kv_out, ssm_out = layers(x, *xs_in)
+            return x, (kv_out, ssm_out)
+
+        x, (kv_new, ssm_new) = jax.lax.scan(
+            body, x, (params["blocks"], caches.kv, caches.ssm, cross))
+    else:
+        def body(carry, xs_in):
+            x, pools = carry
+            block_params, layer, ssm_in, cross_in = xs_in
+            x, pools, ssm_out = layers(x, block_params, pools, ssm_in,
+                                       cross_in, layer)
+            return (x, pools), ssm_out
+
+        xs = (params["blocks"], jnp.arange(n_blocks(cfg), dtype=jnp.int32),
+              caches.ssm, cross)
+        (x, kv_new), ssm_new = jax.lax.scan(body, (x, caches.kv), xs)
     x = rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
     logits = logits_fn(params, x, cfg)[:, 0]
     return logits, Caches(kv=kv_new, ssm=ssm_new, cross=caches.cross)
@@ -666,8 +682,7 @@ def verify_step(
     x = _embed(params, tokens, policy)                  # (B, W, d)
     x = _shard(x, policy, "hidden_decode")
 
-    def body(x, xs_in):
-        block_params, kv_in = xs_in
+    def layers(x, block_params, kv_in, layer=None):
         kv_out = {}
         for p, spec in enumerate(specs):
             lp = block_params[p]
@@ -675,7 +690,7 @@ def verify_step(
             if page_table is not None:
                 y, nkv = attn_mod.paged_verify_attention(
                     lp["attn"], h, kv_in[str(p)], cur_pos, page_table, cfg,
-                    impl=impl, policy=policy,
+                    layer=layer, impl=impl, policy=policy,
                 )
             else:
                 y, nkv = attn_mod.verify_decode_attention(
@@ -694,7 +709,19 @@ def verify_step(
                 x = x + _shard(y2, policy, "mlp_out")
         return x, kv_out
 
-    x, kv_new = jax.lax.scan(body, x, (params["blocks"], caches.kv))
+    if page_table is None:
+        x, kv_new = jax.lax.scan(lambda x, xs_in: layers(x, *xs_in), x,
+                                 (params["blocks"], caches.kv))
+    else:
+        # the stacked pools in the carry, written in place (decode_step)
+        def body(carry, xs_in):
+            x, pools = carry
+            block_params, layer = xs_in
+            return layers(x, block_params, pools, layer), None
+
+        (x, kv_new), _ = jax.lax.scan(
+            body, (x, caches.kv),
+            (params["blocks"], jnp.arange(n_blocks(cfg), dtype=jnp.int32)))
     x = rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
     logits = logits_fn(params, x, cfg)                  # (B, W, Vp)
     return logits, Caches(kv=kv_new, ssm=caches.ssm, cross=caches.cross)

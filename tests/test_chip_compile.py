@@ -16,6 +16,7 @@ every worker imports every test file.  Keep all such tests in this file so
 one worker owns the library.
 """
 
+import math
 import os
 
 import jax
@@ -62,30 +63,97 @@ def _assert_kernel(lowered):
     assert "tpu_custom_call" in hlo
 
 
+def _paged_pool(sharding, page_size, dtype, stacked):
+    """One layer's pool, or the stacked pools of 28 layers and a layer
+    index: the two forms the paged kernel takes."""
+    pool = (257, page_size, HKV, DH)
+    if not stacked:
+        return _spec(sharding, pool, dtype), ()
+    return (_spec(sharding, (28,) + pool, dtype),
+            (_spec(sharding, (), jnp.int32),))
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["layer", "stacked"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 @pytest.mark.parametrize("page_size", [16, 64, 128])
-def test_paged_decode_compiles(one_chip, page_size, dtype):
+def test_paged_decode_compiles(one_chip, page_size, dtype, stacked):
     from repro.kernels.paged_attention import ops
 
-    n_pages, max_pages = 256, 1024 // page_size
-    pool = _spec(one_chip, (n_pages + 1, page_size, HKV, DH), dtype)
+    max_pages = 1024 // page_size
+    pool, layer = _paged_pool(one_chip, page_size, dtype, stacked)
     _assert_kernel(ops.paged_decode_attention.lower(
         _spec(one_chip, (B, H, DH), dtype), pool, pool,
         _spec(one_chip, (B, max_pages), jnp.int32),
-        _spec(one_chip, (B,), jnp.int32), interpret=False))
+        _spec(one_chip, (B,), jnp.int32), *layer, interpret=False))
 
 
+@pytest.mark.parametrize("stacked", [False, True], ids=["layer", "stacked"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 @pytest.mark.parametrize("page_size", [16, 64, 128])
-def test_paged_verify_compiles(one_chip, page_size, dtype):
+def test_paged_verify_compiles(one_chip, page_size, dtype, stacked):
     from repro.kernels.paged_attention import ops
 
-    n_pages, max_pages, W = 256, 1024 // page_size, 4
-    pool = _spec(one_chip, (n_pages + 1, page_size, HKV, DH), dtype)
+    max_pages, W = 1024 // page_size, 4
+    pool, layer = _paged_pool(one_chip, page_size, dtype, stacked)
     _assert_kernel(ops.paged_verify_attention.lower(
         _spec(one_chip, (B, W, H, DH), dtype), pool, pool,
         _spec(one_chip, (B, max_pages), jnp.int32),
-        _spec(one_chip, (B,), jnp.int32), interpret=False))
+        _spec(one_chip, (B,), jnp.int32), *layer, interpret=False))
+
+
+def test_paged_decode_chunk_keeps_pool_in_place(one_chip, monkeypatch):
+    """The paged decode chunk at qwen3-0.6b's widths (28 layers, 16 slots,
+    page 16) updates the stacked K/V pool in place: its temporaries stay
+    far below the pool (a layer scan that passes the pool as xs/ys holds a
+    second pool as a temporary), and no copy, dynamic-slice or
+    dynamic-update-slice yields a layer's pool or the whole stack."""
+    import re
+
+    from repro.configs import get_config
+    from repro.kernels.paged_attention import ops
+    from repro.models import init_paged_caches, init_params
+    from repro.serving.engine import (
+        ServeConfig, init_page_state, init_slot_state,
+        paged_decode_chunk_program)
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    cfg = get_config("qwen3-0.6b")
+    # 2048 pages (3.76 GB of K/V): large enough that the chunk's own
+    # temporaries (~0.18 GB, which do not grow with the pool) sit well
+    # under an eighth of it, small enough to compile in seconds
+    slots, ps, n_pages, max_len = 16, 16, 2048, 1024
+
+    def shaped(tree):
+        return jax.tree.map(
+            lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    caches = shaped(jax.eval_shape(
+        lambda: init_paged_caches(cfg, slots, n_pages, ps)))
+    compiled = paged_decode_chunk_program(
+        cfg, ServeConfig(max_len=max_len, attn_impl="pallas", chunk=8), 8,
+        ps,
+    ).lower(
+        shaped(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))),
+        caches, shaped(init_slot_state(slots)),
+        shaped(init_page_state(slots, n_pages, max_len // ps)),
+        _spec(one_chip, (2,), jnp.uint32),
+    ).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+
+    pool = caches.kv["0"].k
+    pool_bytes = 2 * pool.size * pool.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes / 8, (temp, pool_bytes)
+
+    pool_sized = {pool.size, pool.size // pool.shape[0]}
+    moves = [
+        m.group(0) for m in re.finditer(
+            r"= \w+\[([\d,]*)\]\S* (copy|dynamic-slice|dynamic-update-slice)\(",
+            hlo)
+        if math.prod(int(d) for d in m.group(1).split(",") if d)
+        in pool_sized]
+    assert not moves, moves
 
 
 @pytest.mark.parametrize("C", [1024, 4096])

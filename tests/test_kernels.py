@@ -4,6 +4,8 @@ Each kernel sweeps shapes/dtypes; assert_allclose vs ref.py.  interpret=True
 executes the kernel body on CPU with TPU grid semantics (sequential innermost
 axis, VMEM scratch carried across grid steps)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -209,6 +211,32 @@ class TestPagedDecodeAttention:
         cur = jnp.zeros((B,), jnp.int32)
         got = ops.paged_decode_attention(q, kp, vp, table, cur, interpret=True)
         assert np.isfinite(np.asarray(got)).all()
+
+    @pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "mid", "last"])
+    @pytest.mark.parametrize("W", [1, 4], ids=["decode", "verify"])
+    def test_stacked_pool_matches_layer_pool(self, W, layer):
+        """The stacked pool read at ``layer`` == the layer's own pool: the
+        kernel walks (layer, page) itself, identical bits, including the
+        unmapped pages that load the (poisoned) trash page."""
+        from repro.kernels.paged_attention import ops
+
+        n_layers, B, H, Hkv, dh, P, ps = 5, 3, 4, 2, 32, 10, 8
+        kq, kp_key = jax.random.split(jax.random.fold_in(KEY, W))
+        pools = [self._pools(jax.random.fold_in(kp_key, i), P, ps, Hkv, dh)
+                 for i in range(n_layers)]
+        kp = jnp.stack([k for k, _ in pools])
+        vp = jnp.stack([v for _, v in pools])
+        q = jax.random.normal(kq, (B, W, H, dh), jnp.float32)
+        table = jnp.asarray([[0, 3, -1, -1], [5, -1, 7, -1], [2, 4, 6, 8]],
+                            jnp.int32)
+        cur = jnp.asarray([9, 23, 32 - W], jnp.int32)
+        if W == 1:
+            call = functools.partial(ops.paged_decode_attention, q[:, 0])
+        else:
+            call = functools.partial(ops.paged_verify_attention, q)
+        got = call(kp, vp, table, cur, jnp.int32(layer), interpret=True)
+        want = call(kp[layer], vp[layer], table, cur, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 class TestPrefixAttention:

@@ -26,7 +26,9 @@ def _prompts(cfg, n, seed=3):
             for i in range(n)]
 
 
-def _run(params, cfg, prompts, *, eos_map=None, max_new=10, chunk=8, **kw):
+def _run(params, cfg, prompts, *, eos_map=None, max_new=10, chunk=8,
+         steps=None, **kw):
+    """Serve ``prompts`` to the end, or for ``steps`` rounds only."""
     b = ContinuousBatcher(params, cfg, slots=4, prompt_len=8, max_len=64,
                           chunk=chunk, **kw)
     reqs = [Request(rid=i, prompt=p, max_new=max_new + i % 4,
@@ -34,7 +36,10 @@ def _run(params, cfg, prompts, *, eos_map=None, max_new=10, chunk=8, **kw):
             for i, p in enumerate(prompts)]
     for r in reqs:
         b.submit(r)
-    b.run(max_steps=4000)
+    if steps is None:
+        b.run(max_steps=4000)
+    for _ in range(steps or 0):
+        b.step()
     return b, reqs
 
 
@@ -355,6 +360,45 @@ class TestPallasPagedServing:
                       page_size=4, attn_impl="pallas")
         for a, g in zip(xla, pal):
             assert a.out == g.out, (a.rid, a.out, g.out)
+
+    @pytest.mark.parametrize("speculative", [False, True],
+                             ids=["decode", "spec"])
+    @pytest.mark.parametrize("impl", ["xla", "pallas"])
+    def test_pool_holds_written_positions(self, qwen_f32, impl, speculative):
+        """The paged decode chunk and the paged speculative chunk, on the
+        kernel and on the XLA oracle, write the stacked pool in place:
+        mid-run, every position a live slot has written holds, page by page
+        through its table and in every layer, the K/V a prefill of the
+        slot's committed tokens computes; run on, the streams equal the
+        dense greedy ones."""
+        from repro.models import prefill
+
+        cfg, params = qwen_f32
+        prompts = _prompts(cfg, 4, seed=13)
+        _, dense = _run(params, cfg, prompts, max_new=24, chunk=4)
+        expected = {r.rid: r.out for r in dense}
+        b, reqs = _run(params, cfg, prompts, max_new=24, chunk=4,
+                       paged=True, page_size=4, attn_impl=impl,
+                       speculative=speculative, steps=2)
+        cur = np.asarray(b.state.cur_pos)
+        table = np.asarray(b.pages.table)
+        live = [(i, r) for i, r in enumerate(b.slot_req) if r is not None]
+        assert live and all(cur[i] > 8 for i, _ in live), cur
+        rows = np.zeros((len(live), 64), np.int32)          # max_len
+        for n, (i, r) in enumerate(live):
+            seq = np.concatenate([np.zeros(8 - len(r.prompt), np.int32),
+                                  r.prompt, expected[r.rid]])
+            rows[n, :cur[i]] = seq[:cur[i]]
+        _, ref = prefill(params, jax.numpy.asarray(rows), cfg, max_len=64)
+        pool = b.caches.kv["0"]
+        for n, (i, _) in enumerate(live):
+            pos = np.arange(cur[i])
+            for got, want in ((pool.k, ref.kv["0"].k), (pool.v, ref.kv["0"].v)):
+                np.testing.assert_allclose(
+                    np.asarray(got[:, table[i, pos // 4], pos % 4]),
+                    np.asarray(want[:, n, :cur[i]]), rtol=1e-4, atol=1e-4)
+        b.run(max_steps=4000)
+        assert {r.rid: r.out for r in reqs} == expected
 
 
 class TestAttnCapabilities:
