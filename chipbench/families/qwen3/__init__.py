@@ -1,0 +1,11 @@
+"""The Qwen3 dense decoder: seeded weights (``weights``), the program's
+parameter layout (``model``), the plain reference (``reference``) and the
+operation counts (``flops``)."""
+
+from . import flops
+from .model import make_params, model_config
+from .reference import control_gaps, served_gaps
+from .weights import Dims
+
+__all__ = ["Dims", "control_gaps", "flops", "make_params", "model_config",
+           "served_gaps"]

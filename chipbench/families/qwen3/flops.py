@@ -8,7 +8,7 @@ weights and K/V are bfloat16 (2 bytes).
 
 from __future__ import annotations
 
-from weights import Dims
+from .weights import Dims
 
 BYTES = 2
 

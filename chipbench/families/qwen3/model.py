@@ -2,9 +2,9 @@
 
 ``model_config`` maps a Hugging Face ``config.json`` (as kept under
 ``chipbench/configs/``) onto the program's ``ModelConfig``; ``make_params``
-packs the weights of ``chipbench/weights.py`` into the program's parameter
-tree, on the device, in one jitted call.  This is the only module of the
-benchmark that knows the program's parameter layout.
+packs the weights of ``weights.py`` into the program's parameter tree, on
+the device, in one jitted call.  This is the only module of the family that
+knows the program's parameter layout.
 """
 
 from __future__ import annotations
@@ -14,18 +14,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from weights import Dims, embedding, final_norm, layer_weights, lm_head, \
+from .weights import Dims, embedding, final_norm, layer_weights, lm_head, \
     root_key
-
-#: model types this adapter can map; a new family adds its mapping here
-SUPPORTED = ("qwen3",)
 
 
 def model_config(c: dict):
     from repro.configs.base import ModelConfig
 
-    if c["model_type"] not in SUPPORTED:
-        raise ValueError(f"no mapping for model_type {c['model_type']!r}")
     if c.get("hidden_act", "silu") != "silu" or c.get("attention_bias"):
         raise ValueError("only SwiGLU, bias-free Qwen3 blocks are mapped")
     if c.get("use_sliding_window") or c.get("rope_scaling"):

@@ -6,8 +6,9 @@ A straightforward ``jax.numpy`` forward pass in float32 under
 rotary embedding (theta from the config, the half-split rotation of the
 published model), grouped-query causal attention, SwiGLU, a tied or untied
 LM head.  No cache, no batching, no kernels; nothing of the serving program
-is imported.  Weights are made again from the seed (``chipbench/weights.py``)
-one layer at a time, so the reference fits beside nothing else on the chip.
+is imported.  Weights are made again from the seed (``weights.py`` beside
+this file) one layer at a time, so the reference fits beside nothing else
+on the chip.
 
 ``precision="fp8"`` is the control: every matrix product (projections,
 attention scores and values, LM head) takes its operands rounded to
@@ -18,6 +19,11 @@ The comparison: for each served token, the gap by which the reference's
 logit of that token lies below the reference's best logit at that position.
 The widest gap over a sample of served requests is held against the cell's
 limit.
+
+A sibling family imports the parts it shares rather than copying them
+(``from families.qwen3 import reference as q3``): ``mm`` (with the float8
+control), ``rms``, ``rope``, ``attention``, ``embed``, ``head``,
+``served_rows`` and ``token_gaps``.
 """
 
 from __future__ import annotations
@@ -29,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from weights import Dims, embedding, final_norm, layer_weights, lm_head, \
+from .weights import Dims, embedding, final_norm, layer_weights, lm_head, \
     root_key
 
 BUCKET = 512            # sequences are padded at the end to a multiple
@@ -47,18 +53,18 @@ def _q8(x, axis):
     return (x / s).astype(_F8).astype(jnp.float32) * s
 
 
-def _mm(a, b, prec):
+def mm(a, b, prec):
     """a (..., k) @ b (k, n)."""
     if prec == "fp8":
         a, b = _q8(a, -1), _q8(b, 0)
     return a @ b
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x (S, heads, dh); rotate the two halves of each head."""
     half = x.shape[-1] // 2
     inv = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) * 2 / x.shape[-1])
@@ -68,7 +74,7 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(q, k, v, prec):
+def attention(q, k, v, prec):
     """Causal GQA.  q (S, H, dh); k, v (S, Hkv, dh) -> (S, H, dh)."""
     S, H, dh = q.shape
     g = H // k.shape[1]
@@ -100,35 +106,35 @@ def _layer(key, layer, x, dims: Dims, prec: str):
                      layer_weights(key, layer, dims))
     S = x.shape[0]
     pos = jnp.arange(S)
-    h = _rms(x, w["ln1"], dims.eps)
-    q = _mm(h, w["wq"], prec).reshape(S, dims.n_heads, dims.d_head)
-    k = _mm(h, w["wk"], prec).reshape(S, dims.n_kv, dims.d_head)
-    v = _mm(h, w["wv"], prec).reshape(S, dims.n_kv, dims.d_head)
-    q = _rope(_rms(q, w["q_norm"], dims.eps), pos, dims.theta)
-    k = _rope(_rms(k, w["k_norm"], dims.eps), pos, dims.theta)
-    a = _attention(q, k, v, prec).reshape(S, -1)
-    x = x + _mm(a, w["wo"], prec)
-    h = _rms(x, w["ln2"], dims.eps)
-    f = jax.nn.silu(_mm(h, w["gate"], prec)) * _mm(h, w["up"], prec)
-    return x + _mm(f, w["down"], prec)
+    h = rms(x, w["ln1"], dims.eps)
+    q = mm(h, w["wq"], prec).reshape(S, dims.n_heads, dims.d_head)
+    k = mm(h, w["wk"], prec).reshape(S, dims.n_kv, dims.d_head)
+    v = mm(h, w["wv"], prec).reshape(S, dims.n_kv, dims.d_head)
+    q = rope(rms(q, w["q_norm"], dims.eps), pos, dims.theta)
+    k = rope(rms(k, w["k_norm"], dims.eps), pos, dims.theta)
+    a = attention(q, k, v, prec).reshape(S, -1)
+    x = x + mm(a, w["wo"], prec)
+    h = rms(x, w["ln2"], dims.eps)
+    f = jax.nn.silu(mm(h, w["gate"], prec)) * mm(h, w["up"], prec)
+    return x + mm(f, w["down"], prec)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
-def _embed(key, tokens, dims: Dims):
+def embed(key, tokens, dims: Dims):
     return embedding(key, dims)[tokens].astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
-def _head(key, x, rows, dims: Dims, prec: str):
+def head(key, x, rows, dims: Dims, prec: str):
     """Logits (len(rows), vocab) at positions ``rows`` of ``x``."""
-    h = _rms(x[rows], final_norm(key, dims).astype(jnp.float32), dims.eps)
+    h = rms(x[rows], final_norm(key, dims).astype(jnp.float32), dims.eps)
     w = lm_head(key, dims)
     n = -(-dims.vocab // V_BLOCK)
     w = jnp.pad(w, ((0, n * V_BLOCK - dims.vocab), (0, 0)))
 
     def block(i):
         wb = jax.lax.dynamic_slice_in_dim(w, i * V_BLOCK, V_BLOCK)
-        return _mm(h, wb.astype(jnp.float32).T, prec)
+        return mm(h, wb.astype(jnp.float32).T, prec)
 
     out = jax.lax.map(block, jnp.arange(n))            # (n, P, V_BLOCK)
     return jnp.moveaxis(out, 0, 1).reshape(h.shape[0], -1)[:, :dims.vocab]
@@ -145,10 +151,10 @@ def logits(dims: Dims, seed: int, tokens: Sequence[int], rows: Sequence[int],
     padded = np.zeros(S, np.int32)
     padded[:len(toks)] = toks               # causal: the tail changes nothing
     with jax.default_matmul_precision("highest"):
-        x = _embed(key, jnp.asarray(padded), dims)
+        x = embed(key, jnp.asarray(padded), dims)
         for layer in range(dims.n_layers):
             x = _layer(key, jnp.int32(layer), x, dims, precision)
-        out = _head(key, x, jnp.asarray(np.asarray(rows, np.int32)), dims,
+        out = head(key, x, jnp.asarray(np.asarray(rows, np.int32)), dims,
                     precision)
     return np.asarray(jax.device_get(out), np.float32)
 

@@ -1,15 +1,18 @@
 """Random weights of a Qwen3-style decoder, made from a seed.
 
 The benchmark's own generator: the harness packs what it makes into the
-layout the serving program takes (``chipbench/model.py``), and the plain
-reference (``chipbench/reference.py``) makes the same numbers again, layer by
-layer, without touching the program.  Every tensor is drawn from its own key,
+layout the serving program takes (``model.py``), and the plain reference
+(``reference.py``) makes the same numbers again, layer by layer, without
+touching the program.  Every tensor is drawn from its own key,
 ``fold_in`` of the seed's key with a fixed (group, layer, tensor) path, as
 float32 normals scaled and rounded to the served dtype, bfloat16.
 
 Scales: projections 1/sqrt(fan_in); embedding and LM head 0.02 (the
 configs' ``initializer_range``); RMSNorm scales 1 + 0.1 N(0, 1), so that a
 norm whose scale is dropped or misplaced changes the logits.
+
+Every family draws from :func:`root_key`; a sibling's own tensors may use
+:func:`normal` and :func:`norm_scale` under fold_in paths of its own.
 """
 
 from __future__ import annotations
@@ -67,11 +70,11 @@ def root_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def _normal(key, shape, std, dtype):
+def normal(key, shape, std, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
-def _norm_scale(key, shape, dtype):
+def norm_scale(key, shape, dtype):
     return (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
             ).astype(dtype)
 
@@ -84,15 +87,15 @@ def layer_weights(key, layer, dims: Dims, dtype=jnp.bfloat16):
     for i, (name, shape) in enumerate(dims.layer_shapes().items()):
         k = jax.random.fold_in(lkey, i)
         if len(shape) == 1:
-            out[name] = _norm_scale(k, shape, dtype)
+            out[name] = norm_scale(k, shape, dtype)
         else:
-            out[name] = _normal(k, shape, shape[0] ** -0.5, dtype)
+            out[name] = normal(k, shape, shape[0] ** -0.5, dtype)
     return out
 
 
 def embedding(key, dims: Dims, dtype=jnp.bfloat16):
     """(vocab, d) input embedding; also the LM head when tied."""
-    return _normal(jax.random.fold_in(key, _EMBED), (dims.vocab, dims.d),
+    return normal(jax.random.fold_in(key, _EMBED), (dims.vocab, dims.d),
                    0.02, dtype)
 
 
@@ -100,9 +103,9 @@ def lm_head(key, dims: Dims, dtype=jnp.bfloat16):
     """(vocab, d) output projection (logits = h @ head.T)."""
     if dims.tied:
         return embedding(key, dims, dtype)
-    return _normal(jax.random.fold_in(key, _HEAD), (dims.vocab, dims.d),
+    return normal(jax.random.fold_in(key, _HEAD), (dims.vocab, dims.d),
                    0.02, dtype)
 
 
 def final_norm(key, dims: Dims, dtype=jnp.bfloat16):
-    return _norm_scale(jax.random.fold_in(key, _FINAL_NORM), (dims.d,), dtype)
+    return norm_scale(jax.random.fold_in(key, _FINAL_NORM), (dims.d,), dtype)

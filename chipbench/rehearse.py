@@ -43,8 +43,8 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
+    import family
     import run
-    from model import make_params, model_config
     from repro.models import init_paged_caches
     from repro.serving import ServingConfig
     from repro.serving.engine import (
@@ -63,7 +63,8 @@ def main(argv=None) -> int:
 
     spec = run.load_cell(args.cell, run.read_benchmark())
     config, mix = spec["config"], spec["mix"]
-    cfg = model_config(config)
+    fam = family.load(config)
+    cfg = fam.model_config(config)
     serving = dict(mix["serving"])
     if args.n_pages is not None:
         serving["n_pages"] = args.n_pages
@@ -83,7 +84,7 @@ def main(argv=None) -> int:
     def spec_(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    params = shaped(jax.eval_shape(lambda: make_params(config, 0, cfg)))
+    params = shaped(jax.eval_shape(lambda: fam.make_params(config, 0, cfg)))
     caches = shaped(jax.eval_shape(
         lambda: init_paged_caches(cfg, B, n_pages, ps)))
     state = shaped(jax.eval_shape(lambda: init_slot_state(B)))
@@ -105,13 +106,10 @@ def main(argv=None) -> int:
               f"({peak / 1e9:.2f} GB)", flush=True)
 
     if not only or "params" in only:
-        from model import _make
-        from weights import Dims, root_key
-
+        # the seed is a constant of this program: its key is no argument
         report("make_params", jax.jit(
-            lambda k: _make(k, Dims.from_config(config), cfg.vocab_padded,
-                            cfg.dtype)
-        ).lower(shaped(jax.eval_shape(lambda: root_key(0)))))
+            lambda: fam.make_params(config, 0, cfg), out_shardings=one
+        ).lower())
     if not only or "chunk" in only:
         fn = paged_decode_chunk_program(cfg, ecfg, scfg.chunk, ps)
         report(f"decode_chunk T={scfg.chunk}",

@@ -4,9 +4,10 @@
         --trace <0|1>
 
 A cell of ``BENCHMARK.json`` names a configuration (``configs[].file``) and
-a traffic mix (``chipbench/traffic/<traffic>.json``); its per-layer metrics
-are read by ``chipbench/metrics/<name>.py``.  Nothing here is specific to a
-cell.
+a traffic mix (``chipbench/traffic/<traffic>.json``); the configuration's
+``model_type`` names its family (``chipbench/families/<model_type>/``), and
+its per-layer metrics are read by ``chipbench/metrics/<name>.py``.  Nothing
+here is specific to a cell or a model.
 
 One run, in one process:
 
@@ -23,8 +24,8 @@ One run, in one process:
    ``setup_s``.  With ``--trace 1`` the window is the first ``trace_s``
    seconds, under the profiler;
 6. reads the peak device memory, frees the program's state, and compares
-   a sample of the served requests with the plain reference
-   (``chipbench/reference.py``);
+   a sample of the served requests with the plain reference of the
+   configuration's family (``chipbench/family.py``);
 7. prints the compared numbers with their limits on standard error, and
    the result as the last line of standard output.
 """
@@ -41,6 +42,7 @@ import os
 import shutil
 import sys
 import time
+import zlib
 from pathlib import Path
 from typing import Dict, List, Optional
 
@@ -398,12 +400,9 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     import jax
     import numpy as np
 
-    import flops as F
-    import reference
+    import family
     import traffic
     from clock import CompileClock, percentile
-    from model import make_params, model_config
-    from weights import Dims
 
     from repro.serving import ServingConfig
     from repro.serving.batcher import ContinuousBatcher
@@ -414,10 +413,11 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         f"platform={dev.platform} count={len(jax.devices())}")
     log(f"compile cache: {use_compile_cache()}")
     clock = CompileClock()
-    cfg = model_config(config)
-    dims = Dims.from_config(config)
+    fam = family.load(config)
+    cfg = fam.model_config(config)
+    dims = fam.Dims.from_config(config)
     scfg = ServingConfig(**mix["serving"])
-    params = make_params(config, seed, cfg)
+    params = fam.make_params(config, seed, cfg)
     jax.block_until_ready(params)
     log(f"weights made: {time.perf_counter() - t_start:.3f} s")
     plan = traffic.schedule(mix, seed, dims.vocab)
@@ -525,18 +525,22 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     del b, params, on_open
     gc.collect()
     t_ref = time.perf_counter()
-    gaps = reference.served_gaps(dims, seed, pairs)
+    gaps = fam.served_gaps(dims, seed, pairs)
     widest = float(max((g.max() for g in gaps), default=float("nan")))
     n_cmp = sum(len(g) for g in gaps)
     log(f"reference: {len(pairs)} requests, {n_cmp} served tokens compared "
         f"in {time.perf_counter() - t_ref:.3f} s")
+    log("compared (rid:tokens:crc32): " + " ".join(
+        f"{r.plan.index}:{len(o)}:"
+        f"{zlib.crc32(np.asarray(o, np.int32).tobytes()):08x}"
+        for r, (_, o) in zip(sample, pairs)))
     compared = {"widest_gap": {"value": widest, "limit": chk["limit"]}}
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
     result = {"correct": verdict(compared["widest_gap"], len(pairs), failed),
               "attempted": len(pop), "failed": failed}
     if control:
-        low = reference.control_gaps(dims, seed, pairs)
+        low = fam.control_gaps(dims, seed, pairs)
         compared["control_widest_gap"] = {
             "value": float(max((g.max() for g in low), default=float("nan"))),
             "limit": chk["limit"]}
@@ -554,7 +558,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         m = Measured(dims=dims, page_size=scfg.page_size,
                      peak=peaks[dev.device_kind], trace=reduced, window=win,
                      counters={k: c1[k] - c0[k] for k in c1},
-                     flops=F, ttft_s=ttft)
+                     flops=fam.flops, ttft_s=ttft)
         out = {}
         for spec_m in spec["per_layer"]:
             v = reader(spec_m["name"])(m)
@@ -580,7 +584,8 @@ class Measured:
     """What a per-layer reader may read: the traced window's reduction, the
     batcher's counters (differences over the window), the host's record of
     the decoded and prefilled tokens and of each request's TTFT, the peaks
-    of the chip, and the operation counts of ``chipbench/flops.py``."""
+    of the chip, and the family's operation counts (``flops``) of its
+    sizes (``dims``)."""
 
     dims: object
     page_size: int

@@ -46,19 +46,19 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(run.ROOT / "src"))
     import gc
 
+    import family
     import traffic
     from clock import percentile
-    from model import make_params, model_config
-    from weights import Dims
 
     from repro.serving import ServingConfig
     from repro.serving.batcher import ContinuousBatcher
 
     run.use_compile_cache()
     config, mix = spec["config"], spec["mix"]
-    cfg, dims = model_config(config), Dims.from_config(config)
+    fam = family.load(config)
+    cfg, dims = fam.model_config(config), fam.Dims.from_config(config)
     scfg = ServingConfig(**mix["serving"])
-    params = make_params(config, args.seed, cfg)
+    params = fam.make_params(config, args.seed, cfg)
     run.warm_shapes(params, cfg, scfg, mix, dims.vocab)
     print(f"setup {time.perf_counter() - t_start:.1f} s", flush=True)
     for rate in (float(r) for r in args.rates.split(",")):

@@ -6,8 +6,8 @@ import json
 import pytest
 from conftest import BENCH
 
-import flops
-from weights import Dims
+from families.qwen3 import flops
+from families.qwen3.weights import Dims
 
 
 #: Qwen3-32B's widths (huggingface.co/Qwen/Qwen3-32B config.json), over

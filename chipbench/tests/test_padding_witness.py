@@ -9,9 +9,9 @@ its padding this test fails, and the chat cells can come back."""
 import numpy as np
 from conftest import tiny_config
 
-import reference
-from model import make_params, model_config
-from weights import Dims
+from families.qwen3 import reference
+from families.qwen3.model import make_params, model_config
+from families.qwen3.weights import Dims
 
 SEED = 2**31 + 77
 

@@ -64,7 +64,7 @@ def test_readers_on_a_served_run():
     of four by one row."""
     import jax
 
-    from model import make_params, model_config
+    from families.qwen3.model import make_params, model_config
     from repro.serving import ServingConfig
     from repro.serving.batcher import ContinuousBatcher, Request
 

@@ -9,8 +9,8 @@ Qwen3-0.6B in bfloat16, 32 slots, ``prompt_len`` 512, ``max_len`` 768, paged,
 Pallas), one process serves, per seed, 32 greedy requests at once: 30 with
 prompt lengths drawn as that cell drew them (lognormal, median 128, sigma
 0.7, 32 to 512) and 2 of exactly 512 tokens.  For a sample of them the
-plain reference (``chipbench/reference.py``) reads the widest gap of the
-served tokens twice: on the prompt as sent, and on the row as the batcher
+plain reference (the qwen3 family's) reads the widest gap of the served
+tokens twice: on the prompt as sent, and on the row as the batcher
 padded it.  One JSON line per seed: the widest gap of the short prompts
 against each, and of the full-length ones.  A sound batcher reads alike on
 both for every prompt; this one departs on the prompt as sent only where it
@@ -46,16 +46,15 @@ def main(argv=None) -> int:
         print("witness_padding.py: no TPU", file=sys.stderr)
         return 1
     sys.path.insert(0, str(run.ROOT / "src"))
-    import reference
-    from model import make_params, model_config
-    from weights import Dims
+    import family
 
     from repro.serving import ServingConfig
     from repro.serving.batcher import ContinuousBatcher, Request
 
     run.use_compile_cache()
     config = json.loads((HERE / "configs" / "qwen3-0.6b.json").read_text())
-    cfg, dims = model_config(config), Dims.from_config(config)
+    fam = family.load(config)
+    cfg, dims = fam.model_config(config), fam.Dims.from_config(config)
     scfg = ServingConfig(slots=SHORT + FULL, prompt_len=PROMPT_LEN,
                          max_len=768, attn_impl="pallas", paged=True,
                          page_size=16)
@@ -67,7 +66,7 @@ def main(argv=None) -> int:
                    for n in lens] + \
                   [rng.integers(1, dims.vocab, size=PROMPT_LEN,
                                 dtype=np.int32) for _ in range(FULL)]
-        params = make_params(config, seed, cfg)
+        params = fam.make_params(config, seed, cfg)
         b = ContinuousBatcher(params, cfg, scfg)
         reqs = [Request(rid=i, prompt=p, max_new=MAX_NEW)
                 for i, p in enumerate(prompts)]
@@ -77,12 +76,12 @@ def main(argv=None) -> int:
         outs = [list(r.out) for r in reqs]
         del b, params
         picked = list(range(COMPARED)) + list(range(SHORT, SHORT + FULL))
-        sent = reference.served_gaps(
+        sent = fam.served_gaps(
             dims, seed, [(prompts[i], outs[i]) for i in picked])
         rows = [np.concatenate([np.zeros(PROMPT_LEN - len(prompts[i]),
                                          np.int32), prompts[i]])
                 for i in picked]
-        padded = reference.served_gaps(
+        padded = fam.served_gaps(
             dims, seed, [(rows[j], outs[i]) for j, i in enumerate(picked)])
         short, full = slice(0, COMPARED), slice(COMPARED, None)
         print(json.dumps({
