@@ -35,6 +35,44 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_dtype: str = "float32"
     every: int = 1             # MoE replaces the MLP every `every`-th layer
+    # expert parallelism: this layer holds routed experts
+    # [first_expert, first_expert + n_held) of n_experts (0 = all of them)
+    held: int = 0
+    first_expert: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.n_experts
+
+    @property
+    def whole(self) -> bool:
+        """True when the layer holds every routed expert."""
+        return self.n_held == self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (Peng et al. 2023, as Hugging Face computes it):
+    frequencies between the ``beta_fast`` and ``beta_slow`` rotation counts
+    over ``original_max_position`` blend from extrapolated to interpolated
+    by ``factor``; cos and sin are scaled by ``attention_factor``
+    (0.1 ln(factor) + 1 when not given)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """One layer position's attention: its sliding window (None = full
+    causal) and its rotary embedding (theta, optional YaRN scaling)."""
+
+    window: Optional[int] = None
+    rope_theta: Optional[float] = None   # None: the model's rope_theta
+    yarn: Optional[Yarn] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,6 +118,10 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     m_rope: bool = False             # multimodal 3D RoPE (Qwen2-VL)
     sliding_window: Optional[int] = None   # SWA width (Mixtral)
+    # per-layer attention, repeated over the layers (layer i has kind
+    # attn_pattern[i % len]); empty: every layer is
+    # AttnKind(sliding_window, rope_theta)
+    attn_pattern: Tuple[AttnKind, ...] = ()
     mlp_kind: str = "swiglu"         # swiglu (3·d·dff) | gelu (2·d·dff)
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
@@ -128,6 +170,16 @@ class ModelConfig:
             return True
         return (i + 1) % self.attn_every == 0
 
+    def attn_kind(self, i: int) -> AttnKind:
+        """Layer ``i``'s attention kind, with the model's theta filled in."""
+        if not self.attn_pattern:
+            return AttnKind(window=self.sliding_window,
+                            rope_theta=self.rope_theta)
+        k = self.attn_pattern[i % len(self.attn_pattern)]
+        if k.rope_theta is None:
+            k = dataclasses.replace(k, rope_theta=self.rope_theta)
+        return k
+
     def is_moe_layer(self, i: int) -> bool:
         if self.moe is None:
             return False
@@ -150,7 +202,7 @@ class ModelConfig:
         m = self.moe
         assert m is not None
         router = self.d_model * m.n_experts
-        routed = m.n_experts * 3 * self.d_model * m.expert_d_ff
+        routed = m.n_held * 3 * self.d_model * m.expert_d_ff
         shared = m.n_shared_experts * 3 * self.d_model * (m.shared_d_ff or m.expert_d_ff)
         total = router + routed + shared
         active = (
@@ -204,9 +256,12 @@ class ModelConfig:
         n_active = self.param_count(active_only=True)
         f = 2.0 * n_active
         if seq_len and self.family not in ("ssm",):
-            attn_layers = sum(1 for i in range(self.n_layers) if self.is_attn_layer(i))
-            ctx = min(seq_len, self.sliding_window) if self.sliding_window else seq_len
-            f += attn_layers * 2.0 * 2.0 * ctx * self.q_dim   # QK^T + AV
+            for i in range(self.n_layers):
+                if not self.is_attn_layer(i):
+                    continue
+                w = self.attn_kind(i).window
+                ctx = min(seq_len, w) if w else seq_len
+                f += 2.0 * 2.0 * ctx * self.q_dim             # QK^T + AV
         return f
 
 

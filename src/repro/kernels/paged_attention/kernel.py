@@ -25,6 +25,14 @@ walks the slot's **page table inside the kernel** instead:
   key is attendable iff its page is mapped and its position is not beyond
   the query's — no per-token ``pos`` array needed.
 
+A windowed layer (``window`` = W) gets a fourth scalar-prefetch operand,
+``first`` (B,): the logical page of the table's first entry, which the
+wrapper sliced to the pages the window overlaps.  Grid step ``(b, j)``
+then holds logical page ``first[b] + j``, and keys at positions
+``<= qpos - W`` are masked.  Those lie at the start of the walk; their
+masked scores leave ``m`` at ``NEG_INF`` until the first key in the window
+arrives, whose rescale (``exp(NEG_INF - m)`` = 0) clears what they added.
+
 Grid = (B, max_pages): each cell owns one slot; the logical-page axis is
 innermost and carries the (m, l, acc) online-softmax scratch across steps.
 One block is a whole page with **all kv heads**: the pool is viewed as
@@ -60,11 +68,9 @@ from ..common import NEG_INF
 
 
 def _paged_kernel(
-    gather_ref, cur_ref, layer_ref,           # scalar prefetch (SMEM)
-    q_ref, k_ref, v_ref, o_ref,               # blocks (VMEM)
-    m_ref, l_ref, acc_ref,                     # scratch (VMEM)
-    *, page_size: int, n_pages: int, max_pages: int, n_kv: int,
-    rows: int, group: int,
+    gather_ref, cur_ref, layer_ref, *refs,    # scalar prefetch (SMEM)
+    page_size: int, n_pages: int, max_pages: int, n_kv: int,
+    rows: int, group: int, window,
 ):
     """Query row ``h * rows + w * group + g`` is q-head ``g`` of kv head
     ``h`` at window position ``w``: absolute position ``cur_pos[b] + w``,
@@ -72,6 +78,12 @@ def _paged_kernel(
     verify window's own K/V written by the caller before the kernel runs
     (within-window causality falls out of the same position check).  Key
     column ``o * n_kv + h`` is offset ``o`` of the page, kv head ``h``."""
+    if window is None:
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+        page = pl.program_id(1)
+    else:
+        first_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+        page = first_ref[pl.program_id(0)] + pl.program_id(1)
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -93,10 +105,12 @@ def _paged_kernel(
     row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     same_head = row // rows == col % n_kv
-    pos = j * page_size + col // n_kv
+    pos = page * page_size + col // n_kv
     qpos = cur_ref[b] + (row % rows) // group
     mapped = gather_ref[b, j] < n_pages
     valid = same_head & mapped & (pos <= qpos)
+    if window is not None:
+        valid = valid & (pos > qpos - window)
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]                                # (n_kv*rows, 1)
@@ -117,15 +131,16 @@ def _paged_kernel(
 
 def paged_attention_kernel(
     q, k_pool, v_pool, gather, cur_pos, layer, *, group: int,
-    interpret: bool = False,
+    first=None, window=None, interpret: bool = False,
 ):
     """q: (B, Hkv, R, dh) — R = W*group window-major query rows per kv
     head (R = group for single-token decode); k_pool/v_pool: the stacked
     pools (n_layers, n_pages + 1, ps, Hkv, dh); gather: (B, max_pages)
     int32 physical page per logical page, already sanitized (unmapped ->
     n_pages, the trash page); cur_pos: (B,) int32 position of the first
-    query row; layer: (1,) int32, the pool of the stack to attend.
-    Returns (B, Hkv, R, dh)."""
+    query row; layer: (1,) int32, the pool of the stack to attend;
+    ``window`` with ``first`` (B,) int32, the logical page of ``gather``'s
+    first entry: a windowed layer's walk.  Returns (B, Hkv, R, dh)."""
     B, Hkv, rows, dh = q.shape
     n_layers, n_pages, page_size = k_pool.shape[:3]
     n_pages -= 1                                       # the trash page
@@ -134,19 +149,20 @@ def paged_attention_kernel(
 
     kern = functools.partial(
         _paged_kernel, page_size=page_size, n_pages=n_pages,
-        max_pages=max_pages, n_kv=Hkv, rows=rows, group=group,
+        max_pages=max_pages, n_kv=Hkv, rows=rows, group=group, window=window,
     )
     page = (1, 1, page_size * Hkv, dh)
+    prefetch = (gather, cur_pos, layer) + (() if window is None else (first,))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, nq, dh), lambda b, j, g, c, l: (b, 0, 0)),
+            pl.BlockSpec((1, nq, dh), lambda b, j, g, c, l, *_: (b, 0, 0)),
             # the page walk: physical page id from the prefetched table
-            pl.BlockSpec(page, lambda b, j, g, c, l: (l[0], g[b, j], 0, 0)),
-            pl.BlockSpec(page, lambda b, j, g, c, l: (l[0], g[b, j], 0, 0)),
+            pl.BlockSpec(page, lambda b, j, g, c, l, *_: (l[0], g[b, j], 0, 0)),
+            pl.BlockSpec(page, lambda b, j, g, c, l, *_: (l[0], g[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, nq, dh), lambda b, j, g, c, l: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, nq, dh), lambda b, j, g, c, l, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((nq, 1), jnp.float32),          # m
             pltpu.VMEM((nq, 1), jnp.float32),          # l
@@ -159,6 +175,6 @@ def paged_attention_kernel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nq, dh), q.dtype),
         interpret=interpret,
-    )(gather, cur_pos, layer, q.reshape(B, nq, dh),
+    )(*prefetch, q.reshape(B, nq, dh),
       k_pool.reshape(view), v_pool.reshape(view))
     return out.reshape(B, Hkv, rows, dh)

@@ -9,10 +9,11 @@ import jax.numpy as jnp
 from ..common import NEG_INF
 
 
-def paged_decode_attention_ref(q, k_pool, v_pool, page_table, cur_pos):
+def paged_decode_attention_ref(q, k_pool, v_pool, page_table, cur_pos,
+                               window=None):
     """Same signature as ``ops.paged_decode_attention``: gather the slot's
     pages into a flat (B, max_pages*ps, Hkv, dh) view, mask to mapped pages
-    and positions <= cur_pos, masked softmax."""
+    and positions <= cur_pos (and > cur_pos - window), masked softmax."""
     B, H, dh = q.shape
     n_pages = k_pool.shape[0] - 1
     ps = k_pool.shape[1]
@@ -26,6 +27,8 @@ def paged_decode_attention_ref(q, k_pool, v_pool, page_table, cur_pos):
     vg = v_pool[gather].reshape(B, L, Hkv, dh)
     pos = jnp.arange(L, dtype=jnp.int32)
     valid = (page_table >= 0)[:, pos // ps] & (pos[None, :] <= cur_pos[:, None])
+    if window is not None:
+        valid &= pos[None, :] > cur_pos[:, None] - window
 
     qg = (q.reshape(B, Hkv, group, dh) / jnp.sqrt(jnp.float32(dh))).astype(q.dtype)
     s = jnp.einsum("bgid,bkgd->bgik", qg, kg,
@@ -37,7 +40,8 @@ def paged_decode_attention_ref(q, k_pool, v_pool, page_table, cur_pos):
     return out.reshape(B, H, dh).astype(q.dtype)
 
 
-def paged_verify_attention_ref(q, k_pool, v_pool, page_table, cur_pos):
+def paged_verify_attention_ref(q, k_pool, v_pool, page_table, cur_pos,
+                               window=None):
     """Multi-query twin of :func:`paged_decode_attention_ref`: query ``w``
     sits at absolute position ``cur_pos + w`` and attends mapped keys at
     positions ``<= cur_pos + w``.  Same signature/layout as
@@ -57,6 +61,8 @@ def paged_verify_attention_ref(q, k_pool, v_pool, page_table, cur_pos):
     q_pos = cur_pos[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
     valid = ((page_table >= 0)[:, pos // ps][:, None, :]
              & (pos[None, None, :] <= q_pos[:, :, None]))          # (B, W, L)
+    if window is not None:
+        valid &= pos[None, None, :] > q_pos[:, :, None] - window
 
     qg = (q.reshape(B, W, Hkv, group, dh)
           / jnp.sqrt(jnp.float32(dh))).astype(q.dtype)

@@ -18,6 +18,11 @@ applies the standard causal mask in suffix-local coordinates
 full-sequence causal attention — the cached==cold identity contract.
 Online-softmax scratch (m, l, acc) is carried across both phases, as in
 ``kernels/flash_attention``.
+
+A windowed layer (``window`` = W) also masks cols at or behind
+``row - W`` (absolute positions, the prefix first): prefix blocks wholly
+behind a query block's window are skipped, and their index map clamps to
+the block where the window starts, so they are not fetched either.
 """
 
 from __future__ import annotations
@@ -34,7 +39,7 @@ from ..common import NEG_INF, cdiv
 
 def _pfx_kernel(
     q_ref, pk_ref, pv_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, lp: int, sk: int, q_offset: int,
+    *, lp: int, sk: int, q_offset: int, window,
     block_q: int, block_kp: int, block_ks: int, n_kp: int, n_k: int,
 ):
     qi = pl.program_id(2)
@@ -63,17 +68,27 @@ def _pfx_kernel(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
 
-    @pl.when(ki < n_kp)
+    # suffix-local coordinates; tile-level causal skip as in flash_attention
+    q_lo = qi * block_q + q_offset
+    k_lo = (ki - n_kp) * block_ks
+
+    in_prefix = ki < n_kp
+    if window is not None:      # skip prefix blocks behind the window
+        in_prefix = jnp.logical_and(
+            in_prefix, (ki + 1) * block_kp - 1 > lp + q_lo - window)
+
+    @pl.when(in_prefix)
     def _prefix_phase():
         # every prefix col precedes every suffix row: padding mask only
         cols = ki * block_kp + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_kp), 1)
+        mask = cols < lp
+        if window is not None:
+            rows = lp + q_lo + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_kp), 0)
+            mask = jnp.logical_and(mask, cols > rows - window)
         online_update(pk_ref[0, 0].astype(jnp.float32),
-                      pv_ref[0, 0].astype(jnp.float32), cols < lp)
-
-    # suffix-local coordinates; tile-level causal skip as in flash_attention
-    q_lo = qi * block_q + q_offset
-    k_lo = (ki - n_kp) * block_ks
+                      pv_ref[0, 0].astype(jnp.float32), mask)
 
     @pl.when(jnp.logical_and(ki >= n_kp, k_lo <= q_lo + block_q - 1))
     def _suffix_phase():
@@ -82,6 +97,8 @@ def _pfx_kernel(
         cols = k_lo + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_ks), 1)
         mask = jnp.logical_and(cols < sk, cols <= rows)
+        if window is not None:
+            mask = jnp.logical_and(mask, cols > rows - window)
         online_update(k_ref[0, 0].astype(jnp.float32),
                       v_ref[0, 0].astype(jnp.float32), mask)
 
@@ -92,7 +109,7 @@ def _pfx_kernel(
 
 
 def prefix_flash_attention_kernel(
-    q, pk, pv, k, v, *, q_offset: int = 0,
+    q, pk, pv, k, v, *, q_offset: int = 0, window=None,
     block_q: int = 512, block_k: int = 512, interpret: bool = False,
 ):
     """q: (B, H, Sq, dh); pk/pv: (B, Hkv, Lp, dh); k/v: (B, Hkv, Sk, dh)
@@ -122,13 +139,20 @@ def prefix_flash_attention_kernel(
 
     grid = (B, H, n_q, n_k)
     kern = functools.partial(
-        _pfx_kernel, lp=Lp, sk=Sk, q_offset=q_offset, block_q=block_q,
+        _pfx_kernel, lp=Lp, sk=Sk, q_offset=q_offset, window=window,
+        block_q=block_q,
         block_kp=block_kp, block_ks=block_ks, n_kp=n_kp, n_k=n_k,
     )
     # clamped index maps: during the other phase an operand re-presents its
     # previous block (same index -> no DMA), so phases don't double-fetch
     pfx_map = lambda b, h, qi, ki: (b, h // group,
                                     jnp.minimum(ki, n_kp - 1), 0)
+    if window is not None:
+        # the first prefix block inside query block qi's window
+        def pfx_map(b, h, qi, ki):
+            lo = (Lp + qi * block_q + q_offset - window + 1) // block_kp
+            return (b, h // group,
+                    jnp.clip(ki, jnp.clip(lo, 0, n_kp - 1), n_kp - 1), 0)
     sfx_map = lambda b, h, qi, ki: (b, h // group,
                                     jnp.clip(ki - n_kp, 0, n_ks - 1), 0)
     return pl.pallas_call(

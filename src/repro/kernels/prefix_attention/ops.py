@@ -19,15 +19,17 @@ from .kernel import prefix_flash_attention_kernel
 
 
 @functools.partial(
-    jax.jit, static_argnames=("q_offset", "block_q", "block_k", "interpret"))
+    jax.jit, static_argnames=("q_offset", "window", "block_q", "block_k",
+                              "interpret"))
 def prefix_flash_attention(
-    q, pk, pv, k, v, *, q_offset: int = 0,
+    q, pk, pv, k, v, *, q_offset: int = 0, window: Optional[int] = None,
     block_q: int = 512, block_k: int = 512,
     interpret: Optional[bool] = None,
 ):
     """q: (B, Sq, H, dh); pk/pv: (B, Lp, Hkv, dh); k/v: (B, Sk, Hkv, dh).
     Query row i is suffix position ``q_offset + i`` (chunked admission);
-    it sees the full prefix and suffix cols ``<= q_offset + i``.
+    it sees the full prefix and suffix cols ``<= q_offset + i``, or with
+    ``window`` = W the positions of ``[prefix; suffix]`` within W of it.
     Returns (B, Sq, H, dh)."""
     interpret = default_interpret() if interpret is None else interpret
     B, Sq, H, dh = q.shape
@@ -42,7 +44,7 @@ def prefix_flash_attention(
     if pad_q:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
     out = prefix_flash_attention_kernel(
-        qt, pkt, pvt, kt, vt, q_offset=q_offset,
+        qt, pkt, pvt, kt, vt, q_offset=q_offset, window=window,
         block_q=bq, block_k=block_k, interpret=interpret,
     )
     if pad_q:

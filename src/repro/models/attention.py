@@ -32,6 +32,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.configs.base import AttnKind
+
 from .layers import apply_mrope, apply_rope, init_dense, init_rmsnorm, rmsnorm
 
 NEG_INF = -1e30
@@ -49,7 +51,7 @@ NEG_INF = -1e30
 #   dense           — prefill + dense-cache decode
 #   paged           — paged-pool decode (block-granular KV virtualization)
 #   prefix          — suffix prefill against cached prefix K/V
-#   sliding_window  — any path on a sliding-window arch
+#   sliding_window  — any path on an arch with a windowed layer
 #   verify          — multi-query draft verification (speculative decode);
 #                     "pallas" rides the paged multi-query kernel in paged
 #                     mode and falls back to the XLA multi-query path on
@@ -110,8 +112,22 @@ def init_attention(key, cfg, *, cross: bool = False):
 # ---------------------------------------------------------------------------
 
 
-def _project_qkv(params, x, cfg, *, positions=None, rope: bool = True):
-    """x: (B, S, D) -> q (B,S,H,dh), k/v (B,S,Hkv,dh), rope applied."""
+def layer_kind(cfg, kind: Optional[AttnKind] = None) -> AttnKind:
+    """The attention kind a call runs: ``kind`` as the layer's position in
+    the period gives it, else the model-wide one (``cfg.sliding_window``,
+    ``cfg.rope_theta``), which a model with a per-layer pattern lacks."""
+    if kind is not None:
+        return kind
+    if cfg.attn_pattern:
+        raise ValueError("a per-layer attention pattern needs each layer's "
+                         "kind (transformer.LayerSpec.attn)")
+    return cfg.attn_kind(0)
+
+
+def _project_qkv(params, x, cfg, *, positions=None, rope: bool = True,
+                 kind: Optional[AttnKind] = None):
+    """x: (B, S, D) -> q (B,S,H,dh), k/v (B,S,Hkv,dh), rope applied (the
+    theta and YaRN scaling of ``kind``)."""
     B, S, _ = x.shape
     q = (x @ params["wq"]).reshape(B, S, cfg.n_heads, cfg.d_head)
     k = (x @ params["wk"]).reshape(B, S, cfg.n_kv_heads, cfg.d_head)
@@ -119,17 +135,19 @@ def _project_qkv(params, x, cfg, *, positions=None, rope: bool = True):
     if cfg.qk_norm and "q_norm" in params:
         q = rmsnorm(params["q_norm"], q, eps=cfg.norm_eps)
         k = rmsnorm(params["k_norm"], k, eps=cfg.norm_eps)
-    if rope and cfg.rope_theta > 0:
+    kind = layer_kind(cfg, kind)
+    theta = kind.rope_theta
+    if rope and theta > 0:
         if positions is None:
             positions = jnp.arange(S, dtype=jnp.int32)[None, :]
         if cfg.m_rope:
             if positions.ndim == 2:   # plain (B,S) ids (e.g. text-only decode):
                 positions = jnp.broadcast_to(positions[None], (3,) + positions.shape)
-            q = apply_mrope(q, positions, cfg.rope_theta)
-            k = apply_mrope(k, positions, cfg.rope_theta)
+            q = apply_mrope(q, positions, theta)
+            k = apply_mrope(k, positions, theta)
         else:
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+            q = apply_rope(q, positions, theta, kind.yarn)
+            k = apply_rope(k, positions, theta, kind.yarn)
     return q, k, v
 
 
@@ -378,7 +396,7 @@ def flash_attention_train(q, k, v, *, causal: bool = True,
 def self_attention(
     params, x, cfg, *, positions=None, causal: bool = True,
     impl: str = "xla", q_offset: int = 0, block_k: int = 512,
-    prefix_kv=None,
+    prefix_kv=None, kind: Optional[AttnKind] = None,
 ):
     """Training/prefill self-attention.  Returns (out, (k, v)) so prefill can
     seed the KV cache.
@@ -390,12 +408,12 @@ def self_attention(
     attention — so a suffix prefill over the same tokens/positions
     reproduces the cold prefill's suffix rows.  Callers must pass
     ``positions`` already offset by ``Lp``; the returned (k, v) cover only
-    the fresh suffix.  Requires a non-windowed arch (the prefix would fall
-    out of a sliding window anyway)."""
-    q, k, v = _project_qkv(params, x, cfg, positions=positions)
+    the fresh suffix.  On a windowed layer (``kind.window``) the suffix
+    rows see the prefix positions inside their window only."""
+    kind = layer_kind(cfg, kind)
+    window = kind.window
+    q, k, v = _project_qkv(params, x, cfg, positions=positions, kind=kind)
     if prefix_kv is not None:
-        if cfg.sliding_window:
-            raise ValueError("prefix_kv requires a non-sliding-window arch")
         pk, pv = prefix_kv
         Lp = pk.shape[1]
         if impl == "pallas":
@@ -405,15 +423,16 @@ def self_attention(
             # streams both phases over one grid axis; no concat copy
             out = pfx_ops.prefix_flash_attention(
                 q, pk.astype(k.dtype), pv.astype(v.dtype), k, v,
-                q_offset=q_offset)
+                q_offset=q_offset, window=window)
         else:
             k_att = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
             v_att = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
             if impl == "naive":
                 out = naive_attention(q, k_att, v_att, causal=causal,
-                                      q_offset=q_offset + Lp)
+                                      window=window, q_offset=q_offset + Lp)
             else:
                 out = chunked_flash_attention(q, k_att, v_att, causal=causal,
+                                              window=window,
                                               q_offset=q_offset + Lp,
                                               block_k=block_k)
         B, S, _, _ = q.shape
@@ -423,20 +442,20 @@ def self_attention(
         from repro.kernels.flash_attention import ops as fa_ops
 
         out = fa_ops.flash_attention(
-            q, k, v, causal=causal, window=cfg.sliding_window, q_offset=q_offset
+            q, k, v, causal=causal, window=window, q_offset=q_offset
         )
     elif impl == "naive":
-        out = naive_attention(q, k, v, causal=causal, window=cfg.sliding_window,
+        out = naive_attention(q, k, v, causal=causal, window=window,
                               q_offset=q_offset)
     elif impl == "flash":
         # custom-VJP path: O(S·block) memory THROUGH the backward pass
         out = flash_attention_train(
-            q, k, v, causal=causal, window=cfg.sliding_window,
+            q, k, v, causal=causal, window=window,
             q_offset=q_offset, block_k=block_k,
         )
     else:
         out = chunked_flash_attention(
-            q, k, v, causal=causal, window=cfg.sliding_window,
+            q, k, v, causal=causal, window=window,
             q_offset=q_offset, block_k=block_k,
         )
     B, S, _, _ = q.shape
@@ -483,7 +502,7 @@ class KVCacheView(NamedTuple):
 
 def decode_attention(
     params, x, cache: KVCacheView, cur_pos, cfg, *, impl: str = "xla",
-    policy=None,
+    policy=None, kind: Optional[AttnKind] = None,
 ):
     """x: (B, 1, D); cur_pos: (B,) absolute position of the new token.
 
@@ -496,8 +515,9 @@ def decode_attention(
     can only implement by resharding the whole cache.
     """
     B = x.shape[0]
+    kind = layer_kind(cfg, kind)
     q, k_new, v_new = _project_qkv(
-        params, x, cfg, positions=cur_pos[:, None], rope=True
+        params, x, cfg, positions=cur_pos[:, None], rope=True, kind=kind
     )                                                          # q: (B,1,H,dh)
     C = cache.k.shape[1]
     # RoPE computes in f32 — cast BEFORE the slot write, or `.at[].set`
@@ -522,16 +542,17 @@ def decode_attention(
         from repro.kernels.decode_attention import ops as da_ops
 
         out = da_ops.decode_attention(
-            q[:, 0], k, v, pos, cur_pos, window=cfg.sliding_window
+            q[:, 0], k, v, pos, cur_pos, window=kind.window
         )[:, None]
     else:
-        out = _decode_attn_xla(q, k, v, pos, cur_pos, cfg)
+        out = _decode_attn_xla(q, k, v, pos, cur_pos, cfg, window=kind.window)
     y = out.reshape(B, 1, cfg.q_dim) @ params["wo"]
     return y, KVCacheView(k=k, v=v, pos=pos)
 
 
-def _decode_attn_xla(q, k, v, pos, cur_pos, cfg):
-    """q: (B,1,H,dh); k/v: (B,C,Hkv,dh); pos: (B,C); cur_pos: (B,).
+def _decode_attn_xla(q, k, v, pos, cur_pos, cfg, *, window=None):
+    """q: (B,1,H,dh); k/v: (B,C,Hkv,dh); pos: (B,C); cur_pos: (B,);
+    ``window``: the layer's sliding window, None = full.
 
     K/V stay in cache dtype; the contractions accumulate in f32 via
     ``preferred_element_type`` — materializing ``k.astype(f32)`` copies the
@@ -544,8 +565,8 @@ def _decode_attn_xla(q, k, v, pos, cur_pos, cfg):
     s = jnp.einsum("bgid,bkgd->bgik", qg, k,
                    preferred_element_type=jnp.float32)             # (B,Hkv,g,C)
     valid = (pos >= 0) & (pos <= cur_pos[:, None])
-    if cfg.sliding_window is not None:
-        valid &= pos > (cur_pos[:, None] - cfg.sliding_window)
+    if window is not None:
+        valid &= pos > (cur_pos[:, None] - window)
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bgik,bkgd->bgid", w.astype(v.dtype), v,
@@ -553,10 +574,11 @@ def _decode_attn_xla(q, k, v, pos, cur_pos, cfg):
     return out.reshape(B, 1, H, dh).astype(q.dtype)
 
 
-def init_kv_cache(cfg, batch: int, max_len: int, *, dtype=None) -> KVCacheView:
+def init_kv_cache(cfg, batch: int, max_len: int, *, dtype=None,
+                  window: Optional[int] = None) -> KVCacheView:
     """Cache for ONE attention layer.  Ring-buffer length = min(max_len,
-    window) for sliding-window archs — the O(window) decode-memory property."""
-    C = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
+    window) for a windowed layer — the O(window) decode-memory property."""
+    C = min(max_len, window) if window else max_len
     dt = jnp.dtype(dtype or cfg.dtype)
     return KVCacheView(
         k=jnp.zeros((batch, C, cfg.n_kv_heads, cfg.d_head), dtype=dt),
@@ -608,11 +630,9 @@ class PagedKVView(NamedTuple):
 
 
 def init_paged_kv_cache(cfg, n_pages: int, page_size: int, *, dtype=None) -> PagedKVView:
-    """Page pool for ONE attention layer (+1 trash page).  Paging assumes a
-    full-length cache, i.e. no sliding-window ring (the ring would recycle
-    *within* a slot; pages recycle *across* slots)."""
-    if cfg.sliding_window:
-        raise ValueError("paged KV does not support sliding-window archs")
+    """Page pool for ONE attention layer (+1 trash page).  A windowed layer
+    keeps a slot's whole context in pages too and masks what lies behind
+    its window; the attention reads only the pages the window overlaps."""
     dt = jnp.dtype(dtype or cfg.dtype)
     return PagedKVView(
         k=jnp.zeros((n_pages + 1, page_size, cfg.n_kv_heads, cfg.d_head), dtype=dt),
@@ -621,7 +641,8 @@ def init_paged_kv_cache(cfg, n_pages: int, page_size: int, *, dtype=None) -> Pag
 
 
 def paged_decode_attention(params, x, cache: PagedKVView, cur_pos, page_table,
-                           cfg, *, layer, impl: str = "xla", policy=None):
+                           cfg, *, layer, impl: str = "xla", policy=None,
+                           kind: Optional[AttnKind] = None):
     """Single-token decode against a paged pool.
 
     x: (B, 1, D); cur_pos: (B,) absolute position of the new token;
@@ -642,6 +663,10 @@ def paged_decode_attention(params, x, cache: PagedKVView, cur_pos, page_table,
     becomes the DMA schedule, so only the mapped pages' bytes move, once.
     The XLA path stays as the numerical oracle.
 
+    A windowed layer (``kind.window`` = W) masks keys at positions
+    ``<= cur_pos - W``; the kernel then walks only the ``ceil(W / ps) + 1``
+    pages that can overlap ``[cur_pos - W + 1, cur_pos]``.
+
     The length-sharded ``kv_slot_update`` policy hook is
     dense-cache-only and is rejected loudly instead of silently falling
     back.
@@ -650,8 +675,9 @@ def paged_decode_attention(params, x, cache: PagedKVView, cur_pos, page_table,
         raise NotImplementedError(
             "paged decode does not support a length-sharded KV cache")
     B = x.shape[0]
+    kind = layer_kind(cfg, kind)
     q, k_new, v_new = _project_qkv(
-        params, x, cfg, positions=cur_pos[:, None], rope=True
+        params, x, cfg, positions=cur_pos[:, None], rope=True, kind=kind
     )
     P = cache.n_pages
     ps = cache.page_size
@@ -670,7 +696,8 @@ def paged_decode_attention(params, x, cache: PagedKVView, cur_pos, page_table,
         from repro.kernels.paged_attention import ops as pa_ops
 
         out = pa_ops.paged_decode_attention(
-            q[:, 0], k, v, page_table, cur_pos, layer)[:, None]
+            q[:, 0], k, v, page_table, cur_pos, layer,
+            window=kind.window)[:, None]
     else:
         gather = jnp.where(page_table >= 0, page_table, P)     # (B, maxp)
         kg = k[layer, gather]                                  # (B, maxp, ps, Hkv, dh)
@@ -682,6 +709,8 @@ def paged_decode_attention(params, x, cache: PagedKVView, cur_pos, page_table,
         pos_l = jnp.arange(L, dtype=jnp.int32)                 # flat == absolute
         valid = (page_table >= 0)[:, pos_l // ps] & (
             pos_l[None, :] <= cur_pos[:, None])
+        if kind.window is not None:
+            valid &= pos_l[None, :] > cur_pos[:, None] - kind.window
         out = _paged_attn_xla(q, kg, vg, valid, cfg)
     y = out.reshape(B, 1, cfg.q_dim) @ params["wo"]
     return y, PagedKVView(k=k, v=v)
@@ -720,7 +749,7 @@ def _paged_attn_xla(q, k, v, valid, cfg):
 
 def verify_decode_attention(
     params, x, cache: KVCacheView, cur_pos, cfg, *, impl: str = "xla",
-    policy=None, write_limit=None,
+    policy=None, write_limit=None, kind: Optional[AttnKind] = None,
 ):
     """Multi-query decode attention for draft verification (dense cache).
 
@@ -744,13 +773,16 @@ def verify_decode_attention(
     if policy is not None and getattr(policy, "kv_len_sharded", False):
         raise NotImplementedError(
             "verify decode does not support a length-sharded KV cache")
-    if cfg.sliding_window:
+    kind = layer_kind(cfg, kind)
+    if kind.window:
         raise ValueError(
-            "verify decode does not support sliding-window archs")
+            "verify decode does not support windowed layers (the dense "
+            "ring recycles positions the window still reads)")
     B, W, _ = x.shape
     wi = jnp.arange(W, dtype=jnp.int32)
     pos_w = cur_pos.astype(jnp.int32)[:, None] + wi[None, :]       # (B, W)
-    q, k_new, v_new = _project_qkv(params, x, cfg, positions=pos_w, rope=True)
+    q, k_new, v_new = _project_qkv(params, x, cfg, positions=pos_w, rope=True,
+                                   kind=kind)
     C = cache.k.shape[1]
     assert W <= C, (W, C)       # window slots stay distinct mod C
     k_new = k_new.astype(cache.k.dtype)
@@ -797,7 +829,7 @@ def _verify_attn_xla(q, k, v, pos, q_pos, cfg):
 
 def paged_verify_attention(params, x, cache: PagedKVView, cur_pos,
                            page_table, cfg, *, layer, impl: str = "xla",
-                           policy=None):
+                           policy=None, kind: Optional[AttnKind] = None):
     """Multi-query decode attention for draft verification (paged pool).
 
     x: (B, W, D); cur_pos: (B,) first window position; page_table,
@@ -810,15 +842,18 @@ def paged_verify_attention(params, x, cache: PagedKVView, cur_pos,
 
     ``impl="pallas"`` walks the page table inside the multi-query kernel
     (``repro.kernels.paged_attention.paged_attention_kernel``);
-    ``impl="xla"`` is the gather oracle.
+    ``impl="xla"`` is the gather oracle.  A windowed layer masks as in
+    :func:`paged_decode_attention`, per query position.
     """
     if policy is not None and getattr(policy, "kv_len_sharded", False):
         raise NotImplementedError(
             "paged decode does not support a length-sharded KV cache")
+    kind = layer_kind(cfg, kind)
     B, W, _ = x.shape
     wi = jnp.arange(W, dtype=jnp.int32)
     pos_w = cur_pos.astype(jnp.int32)[:, None] + wi[None, :]       # (B, W)
-    q, k_new, v_new = _project_qkv(params, x, cfg, positions=pos_w, rope=True)
+    q, k_new, v_new = _project_qkv(params, x, cfg, positions=pos_w, rope=True,
+                                   kind=kind)
     P = cache.n_pages
     ps = cache.page_size
     maxp = page_table.shape[1]
@@ -837,7 +872,7 @@ def paged_verify_attention(params, x, cache: PagedKVView, cur_pos,
         from repro.kernels.paged_attention import ops as pa_ops
 
         out = pa_ops.paged_verify_attention(q, k, v, page_table, cur_pos,
-                                            layer)
+                                            layer, window=kind.window)
     else:
         gather = jnp.where(page_table >= 0, page_table, P)         # (B, maxp)
         L = maxp * ps
@@ -846,6 +881,8 @@ def paged_verify_attention(params, x, cache: PagedKVView, cur_pos,
         pos_l = jnp.arange(L, dtype=jnp.int32)                     # absolute
         valid = (page_table >= 0)[:, pos_l // ps][:, None, :] & (
             pos_l[None, None, :] <= pos_w[:, :, None])             # (B, W, L)
+        if kind.window is not None:
+            valid &= pos_l[None, None, :] > pos_w[:, :, None] - kind.window
         out = _paged_verify_attn_xla(q, kg, vg, valid, cfg)
     y = out.reshape(B, W, cfg.q_dim) @ params["wo"]
     return y, PagedKVView(k=k, v=v)

@@ -11,6 +11,7 @@ Whisper stub, where we follow the same convention and note it in DESIGN.md).
 
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
@@ -99,13 +100,50 @@ def rope_freqs(d_head: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(half, dtype=np.float64) / half))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, Dh); positions: broadcastable to (..., S) int32."""
+def yarn_bounds(d_head: int, theta: float, yarn) -> Tuple[int, int]:
+    """(low, high): the frequency indices between which YaRN ramps from
+    extrapolation to interpolation — the dims whose wavelength over the
+    original context completes ``beta_fast`` and ``beta_slow`` rotations."""
+    def dim(rotations):
+        return (d_head * math.log(yarn.original_max_position
+                                  / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim(yarn.beta_slow)), d_head - 1)
+    return low, high
+
+
+def yarn_freqs(d_head: int, theta: float, yarn) -> Tuple[np.ndarray, float]:
+    """YaRN inverse frequencies for half the head dim, and the factor cos
+    and sin are scaled by (host constants)."""
+    extrap = rope_freqs(d_head, theta)
+    interp = extrap / yarn.factor
+    low, high = yarn_bounds(d_head, theta, yarn)
+    ramp = np.clip((np.arange(d_head // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    e = 1.0 - ramp                        # 1: extrapolate, 0: interpolate
+    mscale = yarn.attention_factor
+    if mscale is None:
+        mscale = 0.1 * math.log(yarn.factor) + 1.0
+    return interp * (1.0 - e) + extrap * e, float(mscale)
+
+
+def apply_rope(x, positions, theta: float, yarn=None):
+    """x: (..., S, H, Dh); positions: broadcastable to (..., S) int32.
+    ``yarn`` (a ``configs.base.Yarn``) rescales the frequencies and the
+    rotation's magnitude."""
     d_head = x.shape[-1]
-    inv = jnp.asarray(rope_freqs(d_head, theta), dtype=jnp.float32)
+    if yarn is None:
+        freqs, mscale = rope_freqs(d_head, theta), None
+    else:
+        freqs, mscale = yarn_freqs(d_head, theta, yarn)
+    inv = jnp.asarray(freqs, dtype=jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * inv          # (..., S, half)
     cos = jnp.cos(ang)[..., None, :]                              # (..., S, 1, half)
     sin = jnp.sin(ang)[..., None, :]
+    if mscale is not None:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -1,8 +1,9 @@
-"""Mixture-of-Experts: top-k router + capacity-bounded sort dispatch.
+"""Mixture-of-Experts: top-k router, a capacity-bounded training dispatch
+and a no-drop serving path over this chip's share of the experts.
 
-Two compute paths (selected automatically by token count):
+Two compute paths:
 
-* **grouped-dispatch** (train / prefill) — per batch-row sort-based dispatch
+* **grouped-dispatch** (training) — per batch-row sort-based dispatch
   with a capacity bound, Switch-Transformer style but WITHOUT the O(T·E·C)
   one-hot dispatch tensor: tokens are argsorted by expert id, given a
   position-in-expert via a running offset, scattered into an (E, C, d) buffer,
@@ -11,11 +12,15 @@ Two compute paths (selected automatically by token count):
   roofline.  The sort is vmapped over the batch row, so with batch sharded on
   the "data" axis the sort is *local* (no cross-device sort network).
 
-* **dense-decode** (few tokens) — compute every expert for every token and
-  weight by the (zeroed below top-k) router probs.  Decode is memory-bound on
-  expert weights regardless of dispatch (a 128-request batch activates nearly
-  all experts), so this trades a negligible FLOP increase for zero gather
-  traffic; recorded in DESIGN.md.
+* **expert share** (serving: prefill, admission, decode) — the layer
+  holds experts ``[first_expert, first_expert + n_held)`` of ``n_experts``
+  (expert parallelism: the other shares live on other chips).  The router
+  keeps all ``n_experts`` outputs; every assignment to a held expert is
+  computed, with no capacity, so a served answer does not depend on how
+  the batch was packed.  Assignments are sorted by expert and pushed
+  through two grouped matrix products (:func:`grouped_matmul`: megablox's
+  Pallas kernel) over the held experts only; the result is this
+  share's part of the layer's output.
 
 Shared experts (DeepSeek-MoE) are mathematically a single always-on MLP of
 width n_shared·d_ff and are implemented as such (see test_moe_shared_equiv).
@@ -23,6 +28,7 @@ width n_shared·d_ff and are implemented as such (see test_moe_shared_equiv).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -40,12 +46,12 @@ def init_moe(key, cfg):
     """cfg: ModelConfig with cfg.moe set."""
     m = cfg.moe
     kr, ke1, ke2, ks = jax.random.split(key, 4)
-    d, dff, E = cfg.d_model, m.expert_d_ff, m.n_experts
+    d, dff, E, H = cfg.d_model, m.expert_d_ff, m.n_experts, m.n_held
     p = {
         "router": init_dense(kr, d, E, "float32")["w"],   # router math in f32
-        "wi": (jax.random.normal(ke1, (E, d, 2 * dff), dtype=jnp.float32)
+        "wi": (jax.random.normal(ke1, (H, d, 2 * dff), dtype=jnp.float32)
                * (1.0 / jnp.sqrt(d))).astype(cfg.dtype),
-        "wo": (jax.random.normal(ke2, (E, dff, d), dtype=jnp.float32)
+        "wo": (jax.random.normal(ke2, (H, dff, d), dtype=jnp.float32)
                * (1.0 / jnp.sqrt(dff))).astype(cfg.dtype),
     }
     if m.n_shared_experts:
@@ -162,31 +168,139 @@ def moe_grouped(params, x, cfg, *, capacity: Optional[int] = None, policy=None):
 
 
 # ---------------------------------------------------------------------------
-# Dense decode path
+# Expert share: the serving path (no capacity, held experts only)
 # ---------------------------------------------------------------------------
 
+#: what ``moe_share``'s stats count, in order: assignments routed to held
+#: experts, all assignments, held experts that received at least one
+EXPERT_STATS = ("held_assignments", "assignments", "held_experts_hit")
+#: most assignments one grouped product takes: a longer input (a cold
+#: admission) runs in token chunks, which bounds its temporaries
+MAX_ROWS = 16384
+#: largest k and n tile of the grouped kernel: a 1152 x 896 bfloat16 weight
+#: tile is 2 MB, 4 MB double-buffered, inside the scoped VMEM
+GMM_TILE = 1152
 
-def moe_dense_decode(params, x, cfg):
-    """x: (B, 1, d) or (B, S_small, d): all experts, prob-weighted combine."""
+
+def _tile(x: int, cap: int = GMM_TILE) -> int:
+    """The largest multiple of 128 that divides ``x`` and is at most
+    ``cap`` (``x`` itself when it fits)."""
+    if x <= cap:
+        return x
+    for t in range(cap - cap % 128, 127, -128):
+        if x % t == 0:
+            return t
+    return 128
+
+
+def _gmm(lhs, rhs, sizes, *, interpret: bool):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    m = lhs.shape[0]
+    lhs = jnp.pad(lhs, ((0, (-m) % 128), (0, 0)))
+    out = gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
+              tiling=(128, _tile(lhs.shape[1]), _tile(rhs.shape[2])),
+              interpret=interpret)
+    return out[:m]
+
+
+def grouped_matmul(lhs, rhs, sizes):
+    """``lhs`` (m, k), rows sorted by group; ``rhs`` (G, k, n); ``sizes``
+    (G,): the rows of group g times ``rhs[g]``.  Rows past ``sizes.sum()``
+    are left undefined.
+
+    Megablox's grouped matmul (a Pallas kernel, ``gmm``): its grid visits
+    only the groups that have rows, and reads each one's weights from
+    ``rhs`` where they lie, so ``rhs`` may be the stack of every layer's
+    experts with the other layers' groups empty.  (A layer's experts
+    sliced out of the stack would be copied for the kernel every layer.)
+    Off the TPU the kernel runs in interpret mode, as the repo's other
+    kernels do; the mode follows the platform the program is lowered for,
+    so a program compiled for a described TPU from a CPU process gets the
+    kernel itself."""
+    return jax.lax.platform_dependent(
+        lhs, rhs, sizes, tpu=functools.partial(_gmm, interpret=False),
+        default=functools.partial(_gmm, interpret=True))
+
+
+def moe_share(params, x, cfg, *, mask=None, layer=None):
+    """This layer's share of the experts, every assignment computed.
+
+    x: (B, S, d); ``mask`` (B,) or (B, S) bool marks the tokens the stats
+    count (all when None; the output covers every token regardless).
+    ``params["wi"]`` / ``["wo"]`` are this layer's held experts (H, ...),
+    or, with ``layer`` (an int32 index), the stack of every layer's
+    (nb, H, ...), read in place.  Returns (out (B, S, d), stats (3,) int32
+    by :data:`EXPERT_STATS`).
+
+    The router's softmax and top-k run in float32 over all ``n_experts``;
+    assignments to experts outside ``[first_expert, first_expert +
+    n_held)`` contribute nothing here (another chip holds them).  The held
+    assignments are sorted by expert, gathered, and run through the two
+    grouped products; rows past the held ones are zeroed, not weighted,
+    so whatever the grouped kernel leaves there cannot leak.  Weighted
+    contributions are summed per token in float32."""
     m = cfg.moe
     B, S, d = x.shape
-    x2 = x.reshape(B * S, d)
-    top_p, top_i, _ = router_probs(params, x2, cfg)
-    # scatter normalized top-k probs into a dense (T, E) weight matrix
-    w = jnp.zeros((B * S, m.n_experts), jnp.float32)
-    w = w.at[jnp.arange(B * S)[:, None], top_i].set(top_p)
-    h = jnp.einsum("td,edf->tef", x2, params["wi"])
-    gate, up = jnp.split(h, 2, axis=-1)
-    h = jax.nn.silu(gate) * up
-    y = jnp.einsum("tef,efd->ted", h, params["wo"])
-    out = jnp.einsum("ted,te->td", y, w.astype(y.dtype)).reshape(B, S, d)
+    T, k, H = B * S, m.top_k, m.n_held
+    wi, wo = params["wi"], params["wo"]
+    base = 0
+    if layer is not None:
+        nb = wi.shape[0]
+        wi = wi.reshape(nb * H, *wi.shape[2:])
+        wo = wo.reshape(nb * H, *wo.shape[2:])
+        base = layer * H
+    counted = jnp.ones((T,), bool) if mask is None else \
+        jnp.broadcast_to(mask.reshape(B, -1), (B, S)).reshape(T)
+
+    def share(xc, cc):
+        n = xc.shape[0]
+        top_p, top_i, _ = router_probs(params, xc, cfg)
+        local = top_i.reshape(n * k) - m.first_expert
+        held = (local >= 0) & (local < H)
+        group = jnp.where(held, local, H)              # H: not held here
+        order = jnp.argsort(group, stable=True)        # held first, by expert
+        token = (jnp.arange(n * k, dtype=jnp.int32) // k)[order]
+        weight = top_p.reshape(n * k)[order]
+        keep = held[order]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((wi.shape[0],), jnp.int32),
+            jnp.zeros((H + 1,), jnp.int32).at[group].add(1)[:H], (base,))
+
+        h = grouped_matmul(xc[token], wi, sizes)
+        gate, up = jnp.split(h, 2, axis=-1)
+        h = (jax.nn.silu(gate) * up).astype(x.dtype)
+        y = grouped_matmul(h, wo, sizes)
+        y = jnp.where(keep[:, None], y.astype(jnp.float32) * weight[:, None],
+                      0.0)
+        out = jnp.zeros((n, d), jnp.float32).at[token].add(y)
+
+        counts = jnp.repeat(cc, k)
+        load = jnp.zeros((H + 1,), jnp.int32).at[
+            jnp.where(counts, group, H)].add(1)[:H]
+        return out.astype(x.dtype), load, counts.sum(dtype=jnp.int32)
+
+    x2 = x.reshape(T, d)
+    chunk = max(1, MAX_ROWS // k)
+    if T <= chunk:
+        out, load, total = share(x2, counted)
+    else:                                  # bounded temporaries, in order
+        n_c = -(-T // chunk)
+        pad = n_c * chunk - T
+        xs = jnp.pad(x2, ((0, pad), (0, 0))).reshape(n_c, chunk, d)
+        cs = jnp.pad(counted, (0, pad)).reshape(n_c, chunk)
+        out, load, total = jax.lax.map(lambda a: share(*a), (xs, cs))
+        out = out.reshape(n_c * chunk, d)[:T]
+        load, total = load.sum(0), total.sum()
+    out = out.reshape(B, S, d)
     if "shared" in params:
         out = out + mlp(params["shared"], x)
-    return out, jnp.float32(0.0)
+    stats = jnp.stack([load.sum(), total, (load > 0).sum(dtype=jnp.int32)])
+    return out, stats
 
 
-def moe_apply(params, x, cfg, *, decode: bool = False, policy=None):
-    """Entry point: grouped dispatch for training/prefill, dense for decode."""
-    if decode or x.shape[1] <= 4:
-        return moe_dense_decode(params, x, cfg)
+def moe_apply(params, x, cfg, *, policy=None):
+    """Training entry point: capacity-bounded grouped dispatch."""
+    if not cfg.moe.whole:
+        raise ValueError("training needs every expert (MoEConfig.held = 0)")
     return moe_grouped(params, x, cfg, policy=policy)

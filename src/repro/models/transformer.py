@@ -7,9 +7,22 @@ params.  This keeps the HLO O(P) instead of O(n_layers) — essential for
 compiling 64–80-layer configs at 512 devices on the dry-run host — and makes
 remat policy application uniform (checkpoint around the period body).
 
+A layer position's attention kind (its sliding window and RoPE,
+``ModelConfig.attn_kind``) is part of its :class:`LayerSpec`, so a model
+whose attention kinds repeat (three windowed layers, then a full one) has
+the pattern's length in its period.
+
 Params are nested dicts; caches are pytrees aligned with the period
 structure so prefill can emit them as scan ys and decode can consume/update
 them as scan xs/ys.
+
+The serving entry points (:func:`prefill`, :func:`prefix_prefill`,
+:func:`decode_step`, :func:`verify_step`) run MoE layers through the
+no-drop expert share (``moe.moe_share``); with ``with_stats=True`` they
+also return its counts summed over layers, ``(3,)`` int32 by
+``moe.EXPERT_STATS`` (zeros for a model without experts).  Device-trace
+scopes: ``attn_window`` / ``attn_full`` round each layer's attention,
+``moe`` round its experts.
 """
 
 from __future__ import annotations
@@ -20,6 +33,8 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.configs.base import AttnKind
 
 from . import attention as attn_mod
 from . import moe as moe_mod
@@ -38,6 +53,13 @@ from .ssm import SSMState
 class LayerSpec:
     mixer: str           # "attn" | "ssm"
     mlp: Optional[str]   # "mlp" | "moe" | None (ssm family has no separate MLP)
+    attn: Optional[AttnKind] = None   # an "attn" mixer's window and RoPE
+
+    @property
+    def scope(self) -> str:
+        """The device-trace scope of this layer's attention."""
+        return "attn_window" if self.attn and self.attn.window \
+            else "attn_full"
 
 
 def period_len(cfg) -> int:
@@ -46,6 +68,8 @@ def period_len(cfg) -> int:
         p = cfg.attn_every
     if cfg.moe is not None:
         p = math.lcm(p, cfg.moe.every)
+    if cfg.attn_pattern:
+        p = math.lcm(p, len(cfg.attn_pattern))
     assert cfg.n_layers % p == 0, (cfg.n_layers, p)
     return p
 
@@ -62,8 +86,52 @@ def period_structure(cfg) -> List[LayerSpec]:
             m = "moe" if cfg.is_moe_layer(i) else "mlp"
         if cfg.family == "ssm":
             m = None   # pure mamba blocks carry their own gating/MLP
-        specs.append(LayerSpec(mixer=mixer, mlp=m))
+        specs.append(LayerSpec(mixer=mixer, mlp=m,
+                               attn=cfg.attn_kind(i) if mixer == "attn"
+                               else None))
     return specs
+
+
+def windowed_layers(cfg) -> bool:
+    """Some attention layer of ``cfg`` reads through a sliding window."""
+    return any(s.attn is not None and s.attn.window
+               for s in period_structure(cfg))
+
+
+def _no_stats():
+    return jnp.zeros((3,), jnp.int32)
+
+
+def _expert_stacks(cfg, blocks):
+    """Split each period position's stacked expert weights (``wi``,
+    ``wo``) off the block params that a layer scan slices, for the serving
+    paths.  The grouped-matmul kernel takes its weights as a whole operand,
+    so a layer's experts sliced out of the stack in the scan would be
+    copied out every layer; it is handed the stack and the layer's index
+    instead.  Returns (the blocks for the scan, the stacks by position —
+    None where there are none — and the layer indices to scan, None for a
+    model without experts)."""
+    if cfg.moe is None:
+        return blocks, [None] * len(blocks), None
+    rest, stacks = [], []
+    for bp in blocks:
+        stack = None
+        if "moe" in bp:
+            moe = dict(bp["moe"])
+            stack = {"wi": moe.pop("wi"), "wo": moe.pop("wo")}
+            bp = dict(bp, moe=moe)
+        rest.append(bp)
+        stacks.append(stack)
+    return rest, stacks, jnp.arange(n_blocks(cfg), dtype=jnp.int32)
+
+
+def _serve_moe(lp, stack, h, cfg, *, mask=None, layer=None):
+    """One layer's expert share on a serving path, its experts read from
+    ``stack`` by ``layer`` when given (:func:`_expert_stacks`)."""
+    params = lp["moe"] if stack is None else dict(lp["moe"], **stack)
+    with jax.named_scope("moe"):
+        return moe_mod.moe_share(params, h, cfg, mask=mask,
+                                 layer=None if stack is None else layer)
 
 
 def n_blocks(cfg) -> int:
@@ -166,20 +234,27 @@ class FwdOut(NamedTuple):
 
 def _apply_layer_train(
     lp, spec: LayerSpec, x, cfg, *, positions, impl, policy, enc_kv=None,
-    causal: bool = True, prefix_kv=None,
+    causal: bool = True, prefix_kv=None, serve: bool = False, mask=None,
+    experts=None, layer=None,
 ):
     """One layer, full-sequence (train/prefill shape).  Returns
     (x, aux, kv_or_None, ssm_state_or_None).  ``prefix_kv`` threads a cached
-    K/V context into the attention (shared-prefix suffix prefill)."""
-    aux = jnp.float32(0.0)
+    K/V context into the attention (shared-prefix suffix prefill).
+
+    ``serve`` runs an MoE layer as the serving program does (the no-drop
+    expert share, its stats counting the tokens of ``mask``), and ``aux``
+    is then those stats, (3,) int32, instead of the load-balance loss;
+    ``experts``/``layer`` as :func:`_serve_moe` takes them."""
+    aux = _no_stats() if serve else jnp.float32(0.0)
     kv = None
     sstate = None
     h = rmsnorm(lp["ln1"], x, eps=cfg.norm_eps)
     if spec.mixer == "attn":
-        y, kv = attn_mod.self_attention(
-            lp["attn"], h, cfg, positions=positions, causal=causal, impl=impl,
-            prefix_kv=prefix_kv,
-        )
+        with jax.named_scope(spec.scope):
+            y, kv = attn_mod.self_attention(
+                lp["attn"], h, cfg, positions=positions, causal=causal,
+                impl=impl, prefix_kv=prefix_kv, kind=spec.attn,
+            )
     else:
         y, sstate = ssm_mod.ssm_forward(lp["ssm"], h, cfg, impl=impl, return_state=True)
     x = x + _shard(_shard(y, policy, "attn_out"), policy, "residual")
@@ -188,9 +263,12 @@ def _apply_layer_train(
         x = x + attn_mod.cross_attention(lp["cross"], hx, enc_kv, cfg, impl=impl)
     if spec.mlp is not None:
         h2 = rmsnorm(lp["ln2"], x, eps=cfg.norm_eps)
-        if spec.mlp == "moe":
-            y2, aux = moe_mod.moe_apply(lp["moe"], h2, cfg, decode=False,
-                                        policy=policy)
+        if spec.mlp == "moe" and serve:
+            y2, aux = _serve_moe(lp, experts, h2, cfg, mask=mask,
+                                 layer=layer)
+        elif spec.mlp == "moe":
+            with jax.named_scope("moe"):
+                y2, aux = moe_mod.moe_apply(lp["moe"], h2, cfg, policy=policy)
         else:
             y2 = mlp(lp["mlp"], h2, kind=cfg.mlp_kind)
         x = x + _shard(_shard(y2, policy, "mlp_out"), policy, "residual")
@@ -199,22 +277,27 @@ def _apply_layer_train(
 
 def _apply_layer_decode(
     lp, spec: LayerSpec, x, cfg, *, cur_pos, kv_cache, ssm_state, cross_kv,
-    impl, policy, page_table=None, layer=None,
+    impl, policy, page_table=None, layer=None, mask=None, experts=None,
 ):
-    """One layer, single-token decode.  Returns (x, new_kv, new_ssm).
-    With ``page_table``, ``kv_cache`` is the stacked paged pool and
-    ``layer`` this layer's index into it."""
+    """One layer, single-token decode.  Returns (x, new_kv, new_ssm,
+    stats): ``stats`` the expert share's counts over the slots of
+    ``mask`` (zeros without experts).  With ``page_table``, ``kv_cache``
+    is the stacked paged pool and ``layer`` this layer's index into it."""
     h = rmsnorm(lp["ln1"], x, eps=cfg.norm_eps)
     new_kv, new_ssm = kv_cache, ssm_state
+    stats = _no_stats()
     if spec.mixer == "attn" and page_table is not None:
-        y, new_kv = attn_mod.paged_decode_attention(
-            lp["attn"], h, kv_cache, cur_pos, page_table, cfg,
-            layer=layer, impl=impl, policy=policy,
-        )
+        with jax.named_scope(spec.scope):
+            y, new_kv = attn_mod.paged_decode_attention(
+                lp["attn"], h, kv_cache, cur_pos, page_table, cfg,
+                layer=layer, impl=impl, policy=policy, kind=spec.attn,
+            )
     elif spec.mixer == "attn":
-        y, new_kv = attn_mod.decode_attention(
-            lp["attn"], h, kv_cache, cur_pos, cfg, impl=impl, policy=policy
-        )
+        with jax.named_scope(spec.scope):
+            y, new_kv = attn_mod.decode_attention(
+                lp["attn"], h, kv_cache, cur_pos, cfg, impl=impl,
+                policy=policy, kind=spec.attn,
+            )
     else:
         y, new_ssm = ssm_mod.ssm_decode(lp["ssm"], h, ssm_state, cfg)
     x = x + _shard(y, policy, "attn_out")
@@ -224,12 +307,12 @@ def _apply_layer_decode(
     if spec.mlp is not None:
         h2 = rmsnorm(lp["ln2"], x, eps=cfg.norm_eps)
         if spec.mlp == "moe":
-            y2, _ = moe_mod.moe_apply(lp["moe"], h2, cfg, decode=True,
-                                      policy=policy)
+            y2, stats = _serve_moe(lp, experts, h2, cfg, mask=mask,
+                                   layer=layer)
         else:
             y2 = mlp(lp["mlp"], h2, kind=cfg.mlp_kind)
         x = x + _shard(y2, policy, "mlp_out")
-    return x, new_kv, new_ssm
+    return x, new_kv, new_ssm, stats
 
 
 def _remat_wrap(fn, remat: str):
@@ -396,7 +479,8 @@ def init_caches(cfg, batch: int, max_len: int) -> Caches:
     kv, ssm = {}, {}
     for p, spec in enumerate(specs):
         if spec.mixer == "attn":
-            one = attn_mod.init_kv_cache(cfg, batch, max_len)
+            one = attn_mod.init_kv_cache(cfg, batch, max_len,
+                                         window=spec.attn.window)
             kv[str(p)] = jax.tree.map(
                 lambda a: jnp.broadcast_to(a, (nb,) + a.shape).copy(), one
             )
@@ -458,11 +542,17 @@ def init_paged_caches(cfg, batch: int, n_pages: int, page_size: int) -> Caches:
 def prefill(
     params, tokens, cfg, *, max_len: int, positions=None, extra_embeds=None,
     enc_out=None, impl: str = "xla", policy=None, remat: str = "none",
+    with_stats: bool = False, mask=None, rings: bool = True,
 ):
-    """Run the full prompt, returning (last-token logits, seeded Caches).
+    """Run the full prompt, returning (last-token logits, seeded Caches),
+    and with ``with_stats`` the expert share's counts over the rows of
+    ``mask`` (B,) as a third output.
 
-    The KV buffers are sized ``min(max_len, window)``; prompt K/V are
-    scattered in ring-buffer order (see serving.kv_cache.seed_cache).
+    The KV buffers are sized ``min(max_len, window)`` per layer; prompt K/V
+    are scattered in ring-buffer order (see serving.kv_cache.seed_cache).
+    ``rings=False`` keeps every position of every layer instead (paged
+    admission packs them into pages; a windowed layer masks, it does not
+    recycle).
     """
     from repro.serving.kv_cache import seed_kv_cache, seed_ssm_state
 
@@ -489,25 +579,30 @@ def prefill(
                 )(lp)
             )
 
+    track = with_stats and cfg.moe is not None
+
+    blocks, stacks, layer_ids = _expert_stacks(cfg, params["blocks"])
+
     def body(carry, xs_in):
-        x = carry
-        if enc_kvs is None:
-            (block_params,) = xs_in
-            ekv = [None] * len(specs)
-        else:
-            block_params, ekv = xs_in
+        x, st = carry if track else (carry, None)
+        block_params, layer, ekv = xs_in
+        ekv = ekv or [None] * len(specs)
         outs = {}
         for p, spec in enumerate(specs):
-            x, _, kv, sstate = _apply_layer_train(
+            x, aux, kv, sstate = _apply_layer_train(
                 block_params[p], spec, x, cfg, positions=positions, impl=impl,
-                policy=policy, enc_kv=ekv[p], causal=True,
+                policy=policy, enc_kv=ekv[p], causal=True, serve=True,
+                mask=mask, experts=stacks[p], layer=layer,
             )
             outs[str(p)] = kv if spec.mixer == "attn" else sstate
-        return x, outs
+            if track:
+                st = st + aux
+        return ((x, st) if track else x), outs
 
     body_w = _remat_wrap(body, remat)
-    xs = (params["blocks"],) if enc_kvs is None else (params["blocks"], enc_kvs)
-    x, ys = jax.lax.scan(body_w, x, xs)
+    carry, ys = jax.lax.scan(body_w, (x, _no_stats()) if track else x,
+                             (blocks, layer_ids, enc_kvs))
+    x, st = carry if track else (carry, _no_stats())
     x = rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
     last = x[:, -1:, :]
     logits = logits_fn(params, last, cfg)[:, 0]
@@ -516,18 +611,22 @@ def prefill(
     for p, spec in enumerate(specs):
         if spec.mixer == "attn":
             k, v = ys[str(p)]
-            kv[str(p)] = seed_kv_cache(cfg, k, v, max_len=max_len, seq_positions=positions)
+            kv[str(p)] = seed_kv_cache(cfg, k, v, max_len=max_len,
+                                       seq_positions=positions,
+                                       window=spec.attn.window if rings
+                                       else None)
         else:
             ssm[str(p)] = seed_ssm_state(ys[str(p)])
     cross = None
     if enc_kvs is not None:
         cross = {str(p): enc_kvs[p] for p in range(len(specs))}
-    return logits, Caches(kv=kv, ssm=ssm, cross=cross)
+    out = (logits, Caches(kv=kv, ssm=ssm, cross=cross))
+    return out + (st,) if with_stats else out
 
 
 def prefix_prefill(
     params, tokens, prefix_kv, cfg, *, prefix_len: int, impl: str = "xla",
-    policy=None,
+    policy=None, with_stats: bool = False, mask=None,
 ):
     """Suffix prefill against a cached prompt prefix (shared-prefix
     admission): run only the uncached tail of the prompt, attending to the
@@ -540,7 +639,9 @@ def prefix_prefill(
 
     Returns (last-token logits (B, Vp), {str(p): (k, v)}) where the output
     K/V cover only the suffix, in absolute-position order (ready for page
-    packing).  Because causal attention makes the suffix rows independent
+    packing); with ``with_stats``, the expert share's counts over the rows
+    of ``mask`` (B,) as a third output.  A windowed layer's suffix rows see
+    the prefix positions inside their window, a full layer's all of them.  Because causal attention makes the suffix rows independent
     of whether the prefix was recomputed or read back, this reproduces the
     cold ``prefill``'s suffix exactly (bit-for-bit when the cache dtype is
     the compute dtype — the page store's dtype cast is the only lossy step).
@@ -562,29 +663,40 @@ def prefix_prefill(
     positions = prefix_len + jnp.arange(S, dtype=jnp.int32)[None, :]
     x = _shard(x, policy, "hidden")
 
-    def body(x, xs_in):
-        block_params, pkv = xs_in
+    track = with_stats and cfg.moe is not None
+
+    blocks, stacks, layer_ids = _expert_stacks(cfg, params["blocks"])
+
+    def body(carry, xs_in):
+        x, st = carry if track else (carry, None)
+        block_params, pkv, layer = xs_in
         outs = {}
         for p, spec in enumerate(specs):
-            x, _, kv, _ = _apply_layer_train(
+            x, aux, kv, _ = _apply_layer_train(
                 block_params[p], spec, x, cfg, positions=positions, impl=impl,
                 policy=policy, causal=True, prefix_kv=pkv[str(p)],
+                serve=True, mask=mask, experts=stacks[p], layer=layer,
             )
             outs[str(p)] = kv
-        return x, outs
+            if track:
+                st = st + aux
+        return ((x, st) if track else x), outs
 
-    x, ys = jax.lax.scan(body, x, (params["blocks"], prefix_kv))
+    carry, ys = jax.lax.scan(body, (x, _no_stats()) if track else x,
+                             (blocks, prefix_kv, layer_ids))
+    x, st = carry if track else (carry, _no_stats())
     x = rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
     logits = logits_fn(params, x[:, -1:, :], cfg)[:, 0]
-    return logits, ys
+    return (logits, ys, st) if with_stats else (logits, ys)
 
 
 def decode_step(
     params, tokens, caches: Caches, cur_pos, cfg, *, impl: str = "xla",
-    policy=None, page_table=None,
+    policy=None, page_table=None, with_stats: bool = False, mask=None,
 ):
     """One decode step.  tokens: (B,) int32; cur_pos: (B,) absolute position.
-    Returns (logits (B, Vp), updated Caches).
+    Returns (logits (B, Vp), updated Caches), and with ``with_stats`` the
+    expert share's counts over the slots of ``mask`` (B,).
 
     With ``page_table`` (B, max_pages) the attention caches are treated as
     paged pools (:func:`init_paged_caches`); the table is read-only here —
@@ -603,42 +715,59 @@ def decode_step(
 
     cross = caches.cross or {}
 
+    track = with_stats and cfg.moe is not None
+    blocks, stacks, layer_ids = _expert_stacks(cfg, params["blocks"])
+
     def layers(x, block_params, kv_in, ssm_in, cross_in, layer=None):
         kv_out, ssm_out = {}, {}
+        st = _no_stats()
         for p, spec in enumerate(specs):
-            x, nkv, nssm = _apply_layer_decode(
+            x, nkv, nssm, s_p = _apply_layer_decode(
                 block_params[p], spec, x, cfg, cur_pos=cur_pos,
                 kv_cache=kv_in.get(str(p)), ssm_state=ssm_in.get(str(p)),
                 cross_kv=cross_in.get(str(p)), impl=impl, policy=policy,
-                page_table=page_table, layer=layer,
+                page_table=page_table, layer=layer, mask=mask,
+                experts=stacks[p],
             )
+            st = st + s_p
             if spec.mixer == "attn":
                 kv_out[str(p)] = nkv
             else:
                 ssm_out[str(p)] = nssm
-        return x, kv_out, ssm_out
+        return x, kv_out, ssm_out, st
 
+    st = _no_stats()
     if page_table is None:
-        def body(x, xs_in):
-            x, kv_out, ssm_out = layers(x, *xs_in)
-            return x, (kv_out, ssm_out)
+        def body(carry, xs_in):
+            x, st = carry if track else (carry, None)
+            x, kv_out, ssm_out, s_b = layers(x, *xs_in)
+            return ((x, st + s_b) if track else x), (kv_out, ssm_out)
 
-        x, (kv_new, ssm_new) = jax.lax.scan(
-            body, x, (params["blocks"], caches.kv, caches.ssm, cross))
+        carry, (kv_new, ssm_new) = jax.lax.scan(
+            body, (x, st) if track else x,
+            (blocks, caches.kv, caches.ssm, cross, layer_ids))
+        x, st = carry if track else (carry, st)
     else:
         def body(carry, xs_in):
-            x, pools = carry
+            x, pools = carry[:2]
             block_params, layer, ssm_in, cross_in = xs_in
-            x, pools, ssm_out = layers(x, block_params, pools, ssm_in,
-                                       cross_in, layer)
+            x, pools, ssm_out, s_b = layers(x, block_params, pools, ssm_in,
+                                            cross_in, layer)
+            if track:
+                return (x, pools, carry[2] + s_b), ssm_out
             return (x, pools), ssm_out
 
-        xs = (params["blocks"], jnp.arange(n_blocks(cfg), dtype=jnp.int32),
+        xs = (blocks, jnp.arange(n_blocks(cfg), dtype=jnp.int32),
               caches.ssm, cross)
-        (x, kv_new), ssm_new = jax.lax.scan(body, (x, caches.kv), xs)
+        carry, ssm_new = jax.lax.scan(
+            body, (x, caches.kv, st) if track else (x, caches.kv), xs)
+        x, kv_new = carry[:2]
+        if track:
+            st = carry[2]
     x = rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
     logits = logits_fn(params, x, cfg)[:, 0]
-    return logits, Caches(kv=kv_new, ssm=ssm_new, cross=caches.cross)
+    out = (logits, Caches(kv=kv_new, ssm=ssm_new, cross=caches.cross))
+    return out + (st,) if with_stats else out
 
 
 def verify_step(
@@ -664,10 +793,10 @@ def verify_step(
     caps how many window writes stick in the dense ring (see
     :func:`repro.models.attention.verify_decode_attention`).
 
-    Pure-attention, non-sliding-window archs only: SSM state advances
-    sequentially and cannot be rolled back for free, and audio/vlm prompts
-    carry non-token context.  MoE layers are fine — decode routing is
-    per-token.
+    Pure-attention archs without windowed layers only: SSM state advances
+    sequentially and cannot be rolled back for free, a dense ring recycles
+    positions a window still reads, and audio/vlm prompts carry non-token
+    context.  MoE layers are fine — decode routing is per-token.
     """
     specs = period_structure(cfg)
     if any(s.mixer != "attn" for s in specs):
@@ -677,33 +806,39 @@ def verify_step(
     if cfg.family in ("audio", "vlm"):
         raise ValueError(
             f"verify_step does not support the {cfg.family} family")
-    if cfg.sliding_window:
-        raise ValueError("verify_step does not support sliding-window archs")
+    if windowed_layers(cfg):
+        raise ValueError(
+            "verify_step does not support windowed attention layers "
+            "(speculative decode is for models whose layers all attend in "
+            "full)")
     x = _embed(params, tokens, policy)                  # (B, W, d)
     x = _shard(x, policy, "hidden_decode")
+    blocks, stacks, layer_ids = _expert_stacks(cfg, params["blocks"])
 
     def layers(x, block_params, kv_in, layer=None):
         kv_out = {}
         for p, spec in enumerate(specs):
             lp = block_params[p]
             h = rmsnorm(lp["ln1"], x, eps=cfg.norm_eps)
-            if page_table is not None:
-                y, nkv = attn_mod.paged_verify_attention(
-                    lp["attn"], h, kv_in[str(p)], cur_pos, page_table, cfg,
-                    layer=layer, impl=impl, policy=policy,
-                )
-            else:
-                y, nkv = attn_mod.verify_decode_attention(
-                    lp["attn"], h, kv_in[str(p)], cur_pos, cfg, impl=impl,
-                    policy=policy, write_limit=write_limit,
-                )
+            with jax.named_scope(spec.scope):
+                if page_table is not None:
+                    y, nkv = attn_mod.paged_verify_attention(
+                        lp["attn"], h, kv_in[str(p)], cur_pos, page_table,
+                        cfg, layer=layer, impl=impl, policy=policy,
+                        kind=spec.attn,
+                    )
+                else:
+                    y, nkv = attn_mod.verify_decode_attention(
+                        lp["attn"], h, kv_in[str(p)], cur_pos, cfg,
+                        impl=impl, policy=policy, write_limit=write_limit,
+                        kind=spec.attn,
+                    )
             kv_out[str(p)] = nkv
             x = x + _shard(y, policy, "attn_out")
             if spec.mlp is not None:
                 h2 = rmsnorm(lp["ln2"], x, eps=cfg.norm_eps)
                 if spec.mlp == "moe":
-                    y2, _ = moe_mod.moe_apply(lp["moe"], h2, cfg, decode=True,
-                                              policy=policy)
+                    y2, _ = _serve_moe(lp, stacks[p], h2, cfg, layer=layer)
                 else:
                     y2 = mlp(lp["mlp"], h2, kind=cfg.mlp_kind)
                 x = x + _shard(y2, policy, "mlp_out")
@@ -711,7 +846,7 @@ def verify_step(
 
     if page_table is None:
         x, kv_new = jax.lax.scan(lambda x, xs_in: layers(x, *xs_in), x,
-                                 (params["blocks"], caches.kv))
+                                 (blocks, caches.kv, layer_ids))
     else:
         # the stacked pools in the carry, written in place (decode_step)
         def body(carry, xs_in):
@@ -721,7 +856,7 @@ def verify_step(
 
         (x, kv_new), _ = jax.lax.scan(
             body, (x, caches.kv),
-            (params["blocks"], jnp.arange(n_blocks(cfg), dtype=jnp.int32)))
+            (blocks, jnp.arange(n_blocks(cfg), dtype=jnp.int32)))
     x = rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
     logits = logits_fn(params, x, cfg)                  # (B, W, Vp)
     return logits, Caches(kv=kv_new, ssm=caches.ssm, cross=caches.cross)

@@ -112,8 +112,9 @@ from repro.distributed.sharding import (
     tp_cache_specs, tp_param_specs, tp_put_replicated, tp_shardings,
 )
 from repro.models.attention import check_attn_impl
+from repro.models.moe import EXPERT_STATS
 from repro.models.transformer import (
-    Caches, init_caches, init_paged_caches, period_structure,
+    Caches, init_caches, init_paged_caches, period_structure, windowed_layers,
 )
 from repro.obs import MetricsRegistry, Telemetry
 from .config import ServingConfig, config_from_legacy_kwargs
@@ -208,6 +209,15 @@ _STATS_FIELDS: Tuple[str, ...] = (
     "device_pages_pushed",      # pages pushed back by in-scan frees
     "fault_denied_slots",       # slot-steps denied a page grant in-scan
     "device_draft_accepted",    # draft tokens accepted, counted on-device
+    # expert share (models with experts; ride back in the same syncs):
+    # decode chunks, then admissions — assignments routed to this chip's
+    # experts, all assignments, (layer, held expert) pairs given >= 1 token
+    "moe_held_assignments",
+    "moe_assignments",
+    "moe_held_experts_hit",
+    "admit_moe_held_assignments",
+    "admit_moe_assignments",
+    "admit_moe_held_experts_hit",
     # prefix cache
     "prefix_hits",              # admissions that mapped >= 1 cached page
     "prefill_tokens_skipped",   # prompt tokens served from shared pages
@@ -378,7 +388,8 @@ class ContinuousBatcher:
         self.scfg = scfg
         # structural / capability rules were validated by ServingConfig;
         # the model-dependent rules live here, where cfg is known
-        if cfg.sliding_window:
+        windowed = windowed_layers(cfg)
+        if windowed:
             check_attn_impl(config.attn_impl, "sliding_window")
         if prefix_cache and (
                 any(s.mixer != "attn" for s in period_structure(cfg))
@@ -386,13 +397,16 @@ class ContinuousBatcher:
             raise ValueError(
                 "prefix caching requires a pure-attention arch (SSM state "
                 "is not positional; audio/vlm prompts shift positions)")
+        if config.speculative and windowed:
+            raise ValueError(
+                "speculative decode does not support windowed attention "
+                "layers: every layer must attend in full")
         if config.speculative and (
                 any(s.mixer != "attn" for s in period_structure(cfg))
-                or cfg.family in ("audio", "vlm") or cfg.sliding_window):
+                or cfg.family in ("audio", "vlm")):
             raise ValueError(
-                "speculative decode requires a pure-attention, "
-                "non-sliding-window text arch (SSM state cannot be rolled "
-                "back to the accepted prefix)")
+                "speculative decode requires a pure-attention text arch "
+                "(SSM state cannot be rolled back to the accepted prefix)")
         self._policy = policy
         # tensor parallelism: resolve the tenant sub-mesh before any device
         # state is allocated, so params/caches land sharded from the start
@@ -1202,7 +1216,7 @@ class ContinuousBatcher:
                 fn = cached_admit_program(self.cfg, self.scfg, k,
                                           policy=self._policy,
                                           mesh=self._mesh)
-                nxt, self.caches, self.state, self.pages, out_rows = fn(
+                out = fn(
                     self.params, {"tokens": jnp.asarray(toks)}, self.caches,
                     self.state, self.pages, jnp.asarray(slots),
                     jnp.asarray(pos0), jnp.asarray(budget),
@@ -1210,14 +1224,16 @@ class ContinuousBatcher:
                     jnp.asarray(pin),
                 )
             else:
-                nxt, self.caches, self.state, self.pages, out_rows = \
-                    self._admit_fn(
-                        self.params, {"tokens": jnp.asarray(toks)},
-                        self.caches, self.state, self.pages,
-                        jnp.asarray(slots), jnp.asarray(pos0),
-                        jnp.asarray(budget), jnp.asarray(eos),
-                        jnp.asarray(real), jnp.asarray(pin),
-                    )
+                out = self._admit_fn(
+                    self.params, {"tokens": jnp.asarray(toks)},
+                    self.caches, self.state, self.pages,
+                    jnp.asarray(slots), jnp.asarray(pos0),
+                    jnp.asarray(budget), jnp.asarray(eos),
+                    jnp.asarray(real), jnp.asarray(pin),
+                )
+            # a model with experts adds the share's counts (6th output)
+            (nxt, self.caches, self.state, self.pages, out_rows), experts = \
+                out[:5], out[5:]
         self.stats.prefills += 1
         self.stats.dispatches += 1
         self.stats.admit_scatter_bytes += int(
@@ -1226,7 +1242,13 @@ class ContinuousBatcher:
         )
         self._count_prefill(group, nb, k)
         return {"kind": "paged", "joins": group, "k": k, "nxt": nxt,
-                "out_rows": out_rows, "rows": rows}
+                "out_rows": out_rows, "rows": rows, "experts": experts}
+
+    def _count_experts(self, counts, prefix: str) -> None:
+        """Add an expert-share count vector (``moe.EXPERT_STATS``)."""
+        for name, v in zip(EXPERT_STATS, counts):
+            setattr(self.stats, prefix + name,
+                    getattr(self.stats, prefix + name) + int(v))
 
     def _finish_admit(self, rec: Dict[str, Any]) -> None:
         """Post-dispatch half of one admission: read the first tokens (one
@@ -1234,8 +1256,10 @@ class ContinuousBatcher:
         (``admit.finish``)."""
         with self._tracer.span("admit.sync", self._track):
             if rec["kind"] == "paged":
-                nxt_np, rows_np = jax.device_get(
-                    (rec["nxt"], rec["out_rows"]))
+                nxt_np, rows_np, *experts = jax.device_get(
+                    (rec["nxt"], rec["out_rows"]) + rec["experts"])
+                for e in experts:
+                    self._count_experts(e, "admit_moe_")
             else:
                 nxt_np = np.asarray(jax.device_get(rec["nxt"]))
                 rows_np = None
@@ -1489,6 +1513,8 @@ class ContinuousBatcher:
             self.stats.device_pages_pushed += int(ctr_np[1])
             self.stats.fault_denied_slots += int(ctr_np[2])
             self.stats.device_draft_accepted += int(ctr_np[3])
+            if len(ctr_np) > 4:
+                self._count_experts(ctr_np[4:], "moe_")
             self._stalled = self._stalled + 1 \
                 if int(emit_np.sum()) == 0 else 0
             # a slot that deactivated without finishing was denied a page

@@ -510,14 +510,16 @@ def make_paged_decode_chunk(cfg, scfg: ServeConfig, n_steps: int,
                             page_size: int, *, policy=None):
     """decode_chunk(params, caches, state, pages, key) ->
     (caches, state, pages, tokens (T, B), emitted (T, B), poisoned (B,),
-    ctr (4,) int32).
+    ctr (4,) int32, or (7,) for a model with experts).
 
     ``ctr`` is the chunk's device-counter vector — pages popped off the
     free stack, pages pushed back by in-scan frees, slot-steps denied a
     grant, and (speculative twin only; 0 here) draft tokens accepted —
     accumulated across the scan so the host-side telemetry sees in-chunk
     paging activity without an extra sync (it rides back in the same
-    fetch as the tokens).
+    fetch as the tokens).  A model with experts appends the expert
+    share's counts (``moe.EXPERT_STATS``) of the active slots, summed over
+    steps and layers.
 
     The paged twin of :func:`make_decode_chunk`: same ``lax.scan`` with the
     same EOS/budget bookkeeping, plus **page faults handled inside the
@@ -537,6 +539,7 @@ def make_paged_decode_chunk(cfg, scfg: ServeConfig, n_steps: int,
     """
     mask = scfg.logit_mask(cfg)
     ps = int(page_size)
+    experts = cfg.moe is not None
 
     def decode_chunk(params, caches: Caches, state: SlotState,
                      pages: PageState, key):
@@ -562,10 +565,12 @@ def make_paged_decode_chunk(cfg, scfg: ServeConfig, n_steps: int,
             oom = need & ~got
             active = st.active & ~oom
             # -- decode against the (updated) page table ------------------
-            logits, caches = decode_step(
+            out = decode_step(
                 params, st.tokens, caches, st.cur_pos, cfg,
                 impl=scfg.attn_impl, policy=policy, page_table=table,
+                with_stats=experts, mask=active,
             )
+            logits, caches = out[:2]
             bad = active & ~jnp.isfinite(logits).all(axis=-1)
             active = active & ~bad
             nxt = select_token(logits, mask, scfg, sub)
@@ -579,7 +584,7 @@ def make_paged_decode_chunk(cfg, scfg: ServeConfig, n_steps: int,
                 table, pg.free, ft_pop, done | oom | bad, pg.pinned)
             ctr = ctr + jnp.stack(
                 [popped, free_top - ft_pop, oom.sum(dtype=jnp.int32),
-                 jnp.int32(0)])
+                 jnp.int32(0)] + ([*out[2]] if experts else []))
             st = SlotState(
                 tokens=nxt,
                 cur_pos=st.cur_pos + active.astype(jnp.int32),
@@ -592,7 +597,7 @@ def make_paged_decode_chunk(cfg, scfg: ServeConfig, n_steps: int,
             return (caches, st, pg, key, poisoned | bad, ctr), (nxt, emitted)
 
         poisoned0 = jnp.zeros((B,), bool)
-        ctr0 = jnp.zeros((4,), jnp.int32)
+        ctr0 = jnp.zeros((7 if experts else 4,), jnp.int32)
         (caches, state, pages, _, poisoned, ctr), (toks, emitted) = \
             jax.lax.scan(
                 body, (caches, state, pages, key, poisoned0, ctr0), None,
@@ -648,7 +653,9 @@ def _scatter_fresh_kv(caches_kv, fresh_kv, dest, *, S: int, np_: int,
 
 def make_paged_admit_step(cfg, scfg: ServeConfig, *, policy=None):
     """admit_step(params, batch, caches, state, pages, slots, pos0, budget,
-    eos, real, pin) -> (first_tokens (n,), caches, state, pages, rows).
+    eos, real, pin) -> (first_tokens (n,), caches, state, pages, rows), and
+    for a model with experts a sixth output: the expert share's counts
+    (``moe.EXPERT_STATS``, (3,) int32) over the ``real`` rows.
 
     Paged admission: right-sized bucketed prefill exactly like
     :func:`make_admit_step`, but the fresh K/V is scattered into
@@ -667,6 +674,7 @@ def make_paged_admit_step(cfg, scfg: ServeConfig, *, policy=None):
     ``donate_argnums=(2, 3, 4)``.
     """
     mask = scfg.logit_mask(cfg)
+    experts = cfg.moe is not None
 
     def admit_step(params, batch, caches: Caches, state: SlotState,
                    pages: PageState, slots, pos0, budget, eos, real, pin):
@@ -687,7 +695,9 @@ def make_paged_admit_step(cfg, scfg: ServeConfig, *, policy=None):
             )
         # seed a dense cache sized exactly to the prompt: identity placement,
         # so fresh K/V rows are in absolute-position order for page packing
-        logits, fresh = prefill(params, batch["tokens"], cfg, max_len=S, **kw)
+        out = prefill(params, batch["tokens"], cfg, max_len=S,
+                      with_stats=experts, mask=real, rings=False, **kw)
+        logits, fresh = out[:2]
         if mask is not None:
             logits = logits + mask.astype(logits.dtype)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -736,7 +746,8 @@ def make_paged_admit_step(cfg, scfg: ServeConfig, *, policy=None):
         pages = PageState(table=table, free=pages.free, free_top=free_top,
                           quota=pages.quota,
                           pinned=pages.pinned.at[slots].set(pin_vals))
-        return nxt, Caches(kv=kv, ssm=ssm, cross=cross), state, pages, row
+        return (nxt, Caches(kv=kv, ssm=ssm, cross=cross), state, pages,
+                row) + out[2:]
 
     return admit_step
 
@@ -770,7 +781,8 @@ def paged_admit_program(cfg, scfg: ServeConfig, *, policy=None, mesh=None):
         return _tp_program(
             "paged_admit", cfg, scfg, (), policy, mesh,
             lambda lcfg: make_paged_admit_step(lcfg, scfg, policy=policy),
-            paged=True, n_in=11, cache_in=2, n_out=5, cache_out=1,
+            paged=True, n_in=11, cache_in=2,
+            n_out=5 + (cfg.moe is not None), cache_out=1,
             donate=(2, 3, 4))
     return PROGRAMS.get(
         "paged_admit", cfg, scfg, (), policy,
@@ -783,7 +795,8 @@ def make_cached_admit_step(cfg, scfg: ServeConfig, n_prefix_pages: int,
                            *, policy=None):
     """admit_step(params, batch, caches, state, pages, slots, pos0, budget,
     eos, real, prefix_pids, pin) -> (first_tokens, caches, state, pages,
-    rows) — shared-prefix admission.
+    rows) — shared-prefix admission; a model with experts adds its counts
+    as :func:`make_paged_admit_step` does.
 
     The cached twin of :func:`make_paged_admit_step` for rows whose prompt's
     first ``n_prefix_pages`` logical pages are already resident in the
@@ -803,6 +816,7 @@ def make_cached_admit_step(cfg, scfg: ServeConfig, n_prefix_pages: int,
     cold program.  Jit with ``donate_argnums=(2, 3, 4)``.
     """
     mask = scfg.logit_mask(cfg)
+    experts = cfg.moe is not None
     kp = int(n_prefix_pages)
     assert kp >= 1, "use the cold paged admit program for zero cached pages"
 
@@ -824,10 +838,11 @@ def make_cached_admit_step(cfg, scfg: ServeConfig, n_prefix_pages: int,
 
         prefix_kv = {p: (gather(view.k), gather(view.v))
                      for p, view in caches.kv.items()}
-        logits, ys = prefix_prefill(
+        out = prefix_prefill(
             params, batch["tokens"], prefix_kv, cfg, prefix_len=Lp,
-            impl=scfg.attn_impl, policy=policy,
+            impl=scfg.attn_impl, policy=policy, with_stats=experts, mask=real,
         )
+        logits, ys = out[:2]
         if mask is not None:
             logits = logits + mask.astype(logits.dtype)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -864,7 +879,7 @@ def make_cached_admit_step(cfg, scfg: ServeConfig, n_prefix_pages: int,
                           quota=pages.quota,
                           pinned=pages.pinned.at[slots].set(pin_vals))
         return (nxt, Caches(kv=kv, ssm=caches.ssm, cross=caches.cross),
-                state, pages, row)
+                state, pages, row) + out[2:]
 
     return admit_step
 
@@ -880,7 +895,8 @@ def cached_admit_program(cfg, scfg: ServeConfig, n_prefix_pages: int,
             "cached_admit", cfg, scfg, (int(n_prefix_pages),), policy, mesh,
             lambda lcfg: make_cached_admit_step(lcfg, scfg, n_prefix_pages,
                                                 policy=policy),
-            paged=True, n_in=12, cache_in=2, n_out=5, cache_out=1,
+            paged=True, n_in=12, cache_in=2,
+            n_out=5 + (cfg.moe is not None), cache_out=1,
             donate=(2, 3, 4))
     return PROGRAMS.get(
         "cached_admit", cfg, scfg, (int(n_prefix_pages),), policy,

@@ -19,21 +19,23 @@ from repro.models.attention import KVCacheView
 from repro.models.ssm import SSMState
 
 
-def cache_len(cfg, max_len: int) -> int:
-    return min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
+def cache_len(cfg, max_len: int, window=None) -> int:
+    """Ring length of a layer with sliding ``window`` (None = full)."""
+    return min(max_len, window) if window else max_len
 
 
-def seed_kv_cache(cfg, k, v, *, max_len: int, seq_positions=None) -> KVCacheView:
+def seed_kv_cache(cfg, k, v, *, max_len: int, seq_positions=None,
+                  window=None) -> KVCacheView:
     """Seed a decode cache from prefill K/V.
 
     k, v: (nb, B, S, Hkv, dh) — stacked over blocks (scan ys).
     Ring-buffer placement: absolute position s lands in slot s % C, so decode
     can continue writing at cur_pos % C without any copy.  Only the last C
-    positions are kept (for sliding-window archs C = window; older K/V is
-    dead weight by definition of the mask).
+    positions are kept (for a layer of sliding ``window`` C = window; older
+    K/V is dead weight by definition of the mask).
     """
     nb, B, S, Hkv, dh = k.shape
-    C = cache_len(cfg, max_len)
+    C = cache_len(cfg, max_len, window)
     keep = min(S, C)
     pos = np.arange(S - keep, S)                  # absolute positions kept
     slots = pos % C                               # ring slots (identity if S<=C)
@@ -301,11 +303,11 @@ def kv_cache_bytes(cfg, batch: int, max_len: int) -> int:
 
     specs = period_structure(cfg)
     nb = n_blocks(cfg)
-    C = cache_len(cfg, max_len)
     dt = jnp.dtype(cfg.dtype).itemsize
     total = 0
     for spec in specs:
         if spec.mixer == "attn":
+            C = cache_len(cfg, max_len, spec.attn.window)
             total += nb * batch * C * cfg.n_kv_heads * cfg.d_head * 2 * dt
             total += nb * batch * C * 4                     # pos int32
         else:

@@ -184,3 +184,49 @@ def test_flash_attention_compiles(one_chip, S):
     kv = _spec(one_chip, (2, S, HKV, DH))
     _assert_kernel(ops.flash_attention.lower(
         _spec(one_chip, (2, S, H, DH)), kv, kv, interpret=False))
+
+
+# Mellum2-12B-A2.5B's widths: 32 query / 4 KV heads of 128, window 1024,
+# 16 of 64 experts of width 896 held per layer, d 2304, 7 layers a position
+M_H, M_HKV, M_W = 32, 4, 1024
+
+
+@pytest.mark.parametrize("n_q", [1, 4])
+def test_windowed_paged_compiles(one_chip, n_q):
+    """The windowed page walk (the table sliced to the window's pages, a
+    fourth scalar-prefetch operand) at 4,608 positions of page 16."""
+    from repro.kernels.paged_attention import ops
+
+    pool = _spec(one_chip, (28, 3649, 16, M_HKV, DH))
+    _assert_kernel(ops.paged_verify_attention.lower(
+        _spec(one_chip, (16, n_q, M_H, DH)), pool, pool,
+        _spec(one_chip, (16, 288), jnp.int32),
+        _spec(one_chip, (16,), jnp.int32), _spec(one_chip, (), jnp.int32),
+        window=M_W, interpret=False))
+
+
+def test_windowed_prefix_compiles(one_chip):
+    from repro.kernels.prefix_attention import ops
+
+    pk = _spec(one_chip, (2, 3840, M_HKV, DH))
+    k = _spec(one_chip, (2, 256, M_HKV, DH))
+    _assert_kernel(ops.prefix_flash_attention.lower(
+        _spec(one_chip, (2, 256, M_H, DH)), pk, pk, k, k, window=M_W,
+        interpret=False))
+
+
+@pytest.mark.parametrize("k,n", [(2304, 1792), (896, 2304)])
+def test_expert_gmm_reads_the_stack(one_chip, k, n):
+    """The grouped product of a decode step (128 assignments) over the
+    stack of 7 layers x 16 held experts compiles to the Pallas grouped
+    matmul, and its temporaries are nowhere near one layer's experts: the
+    stack is read where it lies, not copied."""
+    from repro.models.moe import grouped_matmul
+
+    stack = _spec(one_chip, (7 * 16, k, n))
+    compiled = jax.jit(grouped_matmul).lower(
+        _spec(one_chip, (128, k)), stack,
+        _spec(one_chip, (7 * 16,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    layer_bytes = 16 * k * n * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes / 16

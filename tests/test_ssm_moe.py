@@ -65,9 +65,10 @@ class TestSSD:
 
 class TestMoE:
     def test_grouped_equals_dense_decode(self):
-        """The capacity-bucketed (train) MoE path equals the dense decode
-        path when capacity is unbounded — same experts, same weights."""
-        from repro.models.moe import moe_dense_decode, moe_grouped
+        """The capacity-bucketed (train) MoE path equals the serving path
+        (the no-drop expert share, here holding every expert) when capacity
+        is unbounded — same experts, same weights."""
+        from repro.models.moe import moe_grouped, moe_share
 
         cfg = get_reduced("mixtral-8x22b")
         params = init_params(cfg, KEY)
@@ -75,7 +76,7 @@ class TestMoE:
         x = jax.random.normal(KEY, (2, 8, cfg.d_model), jnp.dtype(cfg.dtype)) * 0.3
         S, k = 8, cfg.moe.top_k
         y_train, aux = moe_grouped(lp, x, cfg, capacity=S * k)   # no drops
-        y_dec, _ = moe_dense_decode(lp, x, cfg)
+        y_dec, _ = moe_share(lp, x, cfg)
         np.testing.assert_allclose(
             np.asarray(y_train, np.float32), np.asarray(y_dec, np.float32),
             rtol=5e-2, atol=5e-2,
@@ -108,12 +109,12 @@ class TestMoE:
         params = init_params(cfg, KEY)
         lp = jax.tree.map(lambda a: a[0], params["blocks"][0])["moe"]
         x = jax.random.normal(KEY, (1, 4, cfg.d_model), jnp.dtype(cfg.dtype)) * 0.3
-        y, _ = moe_apply(lp, x, cfg, decode=False)
+        y, _ = moe_apply(lp, x, cfg)
         # zero the routed experts: output must still be nonzero (shared path)
         lp2 = dict(lp)
         lp2["wi"] = jnp.zeros_like(lp["wi"])
         lp2["wo"] = jnp.zeros_like(lp["wo"])
-        y2, _ = moe_apply(lp2, x, cfg, decode=False)
+        y2, _ = moe_apply(lp2, x, cfg)
         assert float(jnp.abs(y2.astype(jnp.float32)).max()) > 0
 
     def test_load_balance_loss_uniform_router(self):
