@@ -101,6 +101,30 @@ def test_paged_verify_compiles(one_chip, page_size, dtype, stacked):
         _spec(one_chip, (B,), jnp.int32), *layer, interpret=False))
 
 
+@pytest.mark.parametrize("n_q", [1, 4], ids=["decode", "verify"])
+@pytest.mark.parametrize("H,Hkv,max_pages,n_pages,window", [
+    (16, 8, 272, 3584, None),
+    (32, 4, 288, 3840, None),
+    (32, 4, 288, 3840, 1024),
+], ids=["docqa", "repoqa-full", "repoqa-window"])
+def test_paged_compiles_at_cell_shapes(one_chip, H, Hkv, max_pages, n_pages,
+                                       window, n_q):
+    """The paged kernel at the benchmark cells' exact decode shapes: 16
+    slots, page 16, a stacked bfloat16 pool of 28 layers; qwen3-0.6b's
+    docqa (8 KV heads, group 2, 272 pages a row) and Mellum's repoqa (4 KV
+    heads, group 8, 288 pages a row, full and 1,024-token windowed
+    layers).  The block size the kernel picks from these shapes must fit
+    the chip's VMEM, or the compile fails."""
+    from repro.kernels.paged_attention import ops
+
+    pool = _spec(one_chip, (28, n_pages + 1, 16, Hkv, DH))
+    _assert_kernel(ops.paged_verify_attention.lower(
+        _spec(one_chip, (16, n_q, H, DH)), pool, pool,
+        _spec(one_chip, (16, max_pages), jnp.int32),
+        _spec(one_chip, (16,), jnp.int32), _spec(one_chip, (), jnp.int32),
+        window=window, interpret=False))
+
+
 def test_paged_decode_chunk_keeps_pool_in_place(one_chip, monkeypatch):
     """The paged decode chunk at qwen3-0.6b's widths (28 layers, 16 slots,
     page 16) updates the stacked K/V pool in place: its temporaries stay

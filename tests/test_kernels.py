@@ -148,6 +148,32 @@ class TestSSDScan:
                                    rtol=2e-3, atol=2e-3)
 
 
+@pytest.fixture
+def pages_per_block(request, monkeypatch):
+    """``P``, the pages a block of the paged kernel's walk moves: ``None``
+    keeps the kernel's own rule, an int forces that block size, so that
+    small test walks cross block edges.  The rule is read while a call is
+    traced, so the entry points' jit caches are cleared around a forced
+    size."""
+    from repro.kernels.paged_attention import kernel, ops
+
+    def clear():
+        ops.paged_decode_attention.clear_cache()
+        ops.paged_verify_attention.clear_cache()
+
+    P = request.param
+    if P is not None:
+        clear()
+        monkeypatch.setattr(kernel, "pages_per_block", lambda *a: P)
+    yield P
+    if P is not None:
+        clear()
+
+
+BLOCKS = dict(argnames="pages_per_block", indirect=True,
+              ids=lambda P: "rule" if P is None else f"P{P}")
+
+
 class TestPagedDecodeAttention:
     """In-kernel page-table walk vs the materialized-gather oracle.
 
@@ -162,34 +188,56 @@ class TestPagedDecodeAttention:
         # poisoned trash page: leaks are loud, not averaged away
         return kp.at[P].set(1e4), vp.at[P].set(1e4)
 
-    @pytest.mark.parametrize("H,Hkv", [(4, 2), (8, 1), (8, 8)])
-    def test_matches_ref(self, H, Hkv):
+    @pytest.mark.parametrize(argvalues=[None, 1, 3], **BLOCKS)
+    @pytest.mark.parametrize("W", [1, 4], ids=["decode", "verify"])
+    @pytest.mark.parametrize("H,Hkv,window", [
+        (4, 2, None), (8, 1, None), (8, 8, None), (4, 2, 20)])
+    def test_matches_ref(self, H, Hkv, window, W, pages_per_block):
+        """Rows, with the walk in blocks of P pages (page 8): holes mid-table
+        (mid-block at P 3); the last query on the last position of block 0
+        (23) and, at W 4, a window across that block edge (22..25); a
+        table full to capacity (7 pages, not a multiple of 3); mapped pages
+        past ``cur_pos`` that hold the poisoned 1e4; ``window`` 20 starts
+        the walk inside a page, later than page 0."""
         from repro.kernels.paged_attention import ops, ref
 
-        B, dh, P, ps, maxp = 3, 32, 10, 8, 4
+        B, dh, P, ps = 5, 32, 12, 8
         kq, kp_key = jax.random.split(KEY)
-        q = jax.random.normal(kq, (B, H, dh), jnp.float32)
+        q = jax.random.normal(kq, (B, W, H, dh), jnp.float32)
         kp, vp = self._pools(kp_key, P, ps, Hkv, dh)
-        # rows: unmapped holes mid-table; cur_pos mid-page (partial last
-        # page), at a page boundary - 1, and at full capacity
-        table = jnp.asarray([[0, 3, -1, -1], [5, -1, 7, -1], [2, 4, 6, 8]],
-                            jnp.int32)
-        cur = jnp.asarray([9, 23, 31], jnp.int32)
-        got = ops.paged_decode_attention(q, kp, vp, table, cur, interpret=True)
-        want = ref.paged_decode_attention_ref(q, kp, vp, table, cur)
+        kp, vp = kp.at[10:12].set(1e4), vp.at[10:12].set(1e4)
+        table = jnp.asarray([[0, 3, -1, -1, -1, -1, -1],
+                             [5, -1, 7, -1, -1, -1, -1],
+                             [2, 4, 6, 8, 1, 9, 0],
+                             [1, 9, 10, 11, -1, -1, -1],
+                             [4, 2, 0, 6, 8, -1, -1]], jnp.int32)
+        last = jnp.asarray([9, 23, 55, 11, 25], jnp.int32)  # last query
+        cur = last - (W - 1)
+        if W == 1:
+            got = ops.paged_decode_attention(q[:, 0], kp, vp, table, cur,
+                                             window=window, interpret=True)
+            want = ref.paged_decode_attention_ref(q[:, 0], kp, vp, table,
+                                                  cur, window=window)
+        else:
+            got = ops.paged_verify_attention(q, kp, vp, table, cur,
+                                             window=window, interpret=True)
+            want = ref.paged_verify_attention_ref(q, kp, vp, table, cur,
+                                                  window=window)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
 
-    def test_page_boundary_sweep(self):
-        """cur_pos crossing every position of a 2-page window: the fused
-        `pos <= cur_pos` mask must flip exactly one key per step."""
+    @pytest.mark.parametrize(argvalues=[None, 1, 2], **BLOCKS)
+    def test_page_boundary_sweep(self, pages_per_block):
+        """cur_pos crossing every position of a 3-page table: the fused
+        `pos <= cur_pos` mask must flip exactly one key per step, and the
+        walk gains a page (and, at P 1 and 2, a block) at each edge."""
         from repro.kernels.paged_attention import ops, ref
 
         B, H, Hkv, dh, P, ps = 1, 4, 2, 32, 4, 8
         kq, kp_key = jax.random.split(KEY)
         kp, vp = self._pools(kp_key, P, ps, Hkv, dh)
-        table = jnp.asarray([[1, 2]], jnp.int32)
-        for cur in range(2 * ps):
+        table = jnp.asarray([[1, 2, 3]], jnp.int32)
+        for cur in range(3 * ps):
             q = jax.random.normal(jax.random.fold_in(kq, cur), (B, H, dh),
                                   jnp.float32)
             c = jnp.asarray([cur], jnp.int32)
@@ -199,25 +247,33 @@ class TestPagedDecodeAttention:
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        rtol=2e-4, atol=2e-4, err_msg=f"cur={cur}")
 
-    def test_fully_unmapped_slot_is_finite(self):
+    @pytest.mark.parametrize(argvalues=[None, 1], **BLOCKS)
+    def test_fully_unmapped_slot_is_finite(self, pages_per_block):
         """An inactive slot (all pages -1) must not produce NaN/inf — the
-        batcher keeps dead slots decoding with frozen positions."""
-        from repro.kernels.paged_attention import ops
+        batcher keeps dead slots decoding with frozen positions.  It moves
+        no page and writes zeros, beside a live slot that does not."""
+        from repro.kernels.paged_attention import ops, ref
 
-        B, H, Hkv, dh, P, ps = 2, 4, 2, 32, 4, 8
+        B, H, Hkv, dh, P, ps = 3, 4, 2, 32, 4, 8
         q = jax.random.normal(KEY, (B, H, dh), jnp.float32)
         kp, vp = self._pools(jax.random.fold_in(KEY, 1), P, ps, Hkv, dh)
-        table = jnp.full((B, 2), -1, jnp.int32)
-        cur = jnp.zeros((B,), jnp.int32)
-        got = ops.paged_decode_attention(q, kp, vp, table, cur, interpret=True)
-        assert np.isfinite(np.asarray(got)).all()
+        table = jnp.asarray([[-1, -1], [2, 0], [-1, -1]], jnp.int32)
+        cur = jnp.asarray([0, 12, 15], jnp.int32)
+        got = np.asarray(ops.paged_decode_attention(q, kp, vp, table, cur,
+                                                    interpret=True))
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got[[0, 2]], 0.0)
+        want = ref.paged_decode_attention_ref(q, kp, vp, table, cur)
+        np.testing.assert_allclose(got[1], np.asarray(want)[1],
+                                   rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize(argvalues=[None, 3], **BLOCKS)
     @pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "mid", "last"])
     @pytest.mark.parametrize("W", [1, 4], ids=["decode", "verify"])
-    def test_stacked_pool_matches_layer_pool(self, W, layer):
+    def test_stacked_pool_matches_layer_pool(self, W, layer, pages_per_block):
         """The stacked pool read at ``layer`` == the layer's own pool: the
         kernel walks (layer, page) itself, identical bits, including the
-        unmapped pages that load the (poisoned) trash page."""
+        unmapped pages it must not read (the trash page is poisoned)."""
         from repro.kernels.paged_attention import ops
 
         n_layers, B, H, Hkv, dh, P, ps = 5, 3, 4, 2, 32, 10, 8
@@ -237,6 +293,35 @@ class TestPagedDecodeAttention:
         got = call(kp, vp, table, cur, jnp.int32(layer), interpret=True)
         want = call(kp[layer], vp[layer], table, cur, interpret=True)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("walk,Hkv,rows,itemsize", [
+        (272, 8, 2, 2), (272, 8, 8, 2), (288, 4, 8, 2), (65, 4, 8, 2),
+        (65, 4, 32, 2), (8, 8, 2, 4), (3, 8, 2, 2), (1, 4, 2, 2)])
+    def test_pages_per_block_follows_shapes(self, walk, Hkv, rows, itemsize):
+        """The block size comes from static shapes alone (page 16, dh 128:
+        docqa's and repoqa's walks, decode and verify rows, a float32 pool,
+        short walks): K/V buffers and scores inside the VMEM budget, as few
+        blocks as that budget allows and each as even as they can be, key
+        columns in whole 128-lane vregs; smaller pages, more of them."""
+        from repro.kernels.common import cdiv
+        from repro.kernels.paged_attention import kernel
+
+        def rule(walk, Hkv, rows, itemsize):
+            span = 16 * Hkv
+            page, score = span * 128 * itemsize, Hkv * rows * span * 4
+            return kernel.pages_per_block(walk, page, score, span), \
+                4 * page + 2 * score, 128 // np.gcd(span, 128)
+
+        P, per_page, step = rule(walk, Hkv, rows, itemsize)
+        most = max(1, kernel.VMEM_BUDGET // per_page)
+        assert P * 16 * Hkv % 128 == 0
+        assert P <= max(most, step) + step - 1
+        n_blocks = cdiv(walk, P)
+        assert n_blocks == cdiv(walk, most)            # fewest blocks
+        assert (n_blocks - 1) * P < walk <= n_blocks * P
+        assert P - step < cdiv(walk, n_blocks)         # evened out
+        if Hkv == 8 and walk > 8:                      # 32 KB pages
+            assert P < rule(walk, 4, rows, itemsize)[0]
 
 
 class TestPrefixAttention:
